@@ -84,6 +84,10 @@ class PushReport:
     enc_raw_bytes: int = 0       # raw bytes fed through a codec encoder
     fp_bytes: int = 0            # raw bytes fingerprinted on device
     fp_clean_chunks: int = 0     # chunks proven clean by fingerprint alone
+    # leaves given a parent-relative codec, by the pass that encoded them:
+    # the fused device kernels, or fingerprints plus the host codecs
+    fused_leaves: int = 0
+    host_codec_leaves: int = 0
 
     def __post_init__(self):
         if self.delta_bytes < 0:
@@ -218,7 +222,7 @@ class Registry:
                        if parent is not None else set())
         cb = self.chunk_bytes
         total = written = written_raw = delta = wire = n_chunks = 0
-        enc_raw = fp_bytes = fp_clean = 0
+        enc_raw = fp_bytes = fp_clean = fused = host_codec = 0
         parent_raw_memo: Dict[tuple, bytes] = {}
         lossy = False
         manifest: Dict[str, Any] = {"version": 2, "trees": {},
@@ -240,8 +244,10 @@ class Registry:
                                          parent_raw_memo)
                         if fingerprints else None)
                 if fenc is not None:
+                    fused += 1
                     fps = fenc.fps
                 else:
+                    host_codec += codec_name != "none"
                     fps = (leaf_fingerprints(leaf, cb)
                            if fingerprints else None)
                 if fps is not None:
@@ -344,7 +350,8 @@ class Registry:
                           delta_bytes=delta if parent is not None else total,
                           wire_bytes=wire if parent is not None else total,
                           codec=spec_str, lossy=lossy, enc_raw_bytes=enc_raw,
-                          fp_bytes=fp_bytes, fp_clean_chunks=fp_clean)
+                          fp_bytes=fp_bytes, fp_clean_chunks=fp_clean,
+                          fused_leaves=fused, host_codec_leaves=host_codec)
 
     def push_image(self, trees: Dict[str, Any], meta: Optional[dict] = None,
                    tag: Optional[str] = None, *,

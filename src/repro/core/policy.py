@@ -123,6 +123,10 @@ class MigrationReport:
     image_raw_bytes: int = 0
     image_wire_bytes: int = 0
     compression: str = "none"
+    # leaves encoded by the fused device kernels vs by the host codecs,
+    # summed over every push (``PushReport.fused_leaves`` and friends)
+    fused_leaves: int = 0
+    host_codec_leaves: int = 0
     state_verified: Optional[bool] = None
     # which attempt (1-based) this report describes: > 1 means earlier
     # attempts failed, were rolled back and retried by the orchestrator
@@ -153,6 +157,10 @@ class MigrationReport:
         if self.image_wire_bytes <= 0:
             return 1.0
         return self.image_raw_bytes / self.image_wire_bytes
+
+    def count_codec_leaves(self, push) -> None:
+        self.fused_leaves += push.fused_leaves
+        self.host_codec_leaves += push.host_codec_leaves
 
     def emit(self, kind: str, t: float, **data: Any) -> MigrationEvent:
         ev = MigrationEvent(t=t, kind=kind, data=data)

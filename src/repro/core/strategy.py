@@ -403,6 +403,7 @@ class MigrationContext:
         rep.image_deduped_bytes = push.deduped_bytes
         rep.image_raw_bytes += push.delta_bytes
         rep.image_wire_bytes += push.wire_bytes
+        rep.count_codec_leaves(push)
         self.phase("image_build_push", t0)
         return ckpt, push
 
@@ -607,6 +608,7 @@ class IterativePrecopyTransfer(TransferEngine):
             rep.image_deduped_bytes += delta.deduped_bytes
             rep.image_raw_bytes += delta.delta_bytes
             rep.image_wire_bytes += delta.wire_bytes
+            rep.count_codec_leaves(delta)
             ctx.emit("precopy_round", round=rep.precopy_rounds,
                      bytes=delta.delta_bytes, wire=delta.wire_bytes,
                      dirty=dirty)
@@ -640,6 +642,7 @@ class IterativePrecopyTransfer(TransferEngine):
             rep.image_deduped_bytes += flush.deduped_bytes
             rep.image_raw_bytes += flush.delta_bytes
             rep.image_wire_bytes += flush.wire_bytes
+            rep.count_codec_leaves(flush)
             ctx.emit("precopy_exact_flush", bytes=flush.delta_bytes,
                      wire=flush.wire_bytes)
         rep.checkpoint_marker = marker

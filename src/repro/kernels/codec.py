@@ -49,12 +49,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams as _CompilerParams
 from repro.kernels.fingerprint import (
     LANES,
-    _fit_rows,
-    _row_weights,
+    LANES_SPEC,
+    accumulate_lanes,
+    as_i32,
+    as_u32,
     fingerprint_lanes_ref,
+    pad_rows,
+    row_plan,
+    row_weights_i32,
 )
 
 QBLOCK = 256                 # quant block length == optim.compression.BLOCK
@@ -80,21 +84,11 @@ def pair_rows(words):
 
 def _xor_fp_kernel(cur_ref, par_ref, fp_ref, xor_ref, acc_ref, *,
                    block_rows: int, n_blocks: int):
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    cur = cur_ref[0]
-    xor_ref[0] = cur ^ par_ref[0]
-    row0 = (j * block_rows).astype(jnp.uint32)
-    weighted = cur * _row_weights(row0, block_rows)
-    acc_ref[0] = acc_ref[0] + jnp.sum(weighted, axis=0, dtype=jnp.uint32)
-
-    @pl.when(j == n_blocks - 1)
-    def _done():
-        fp_ref[...] = acc_ref[...]
+    cur = cur_ref[...]
+    xor_ref[...] = cur ^ par_ref[...]
+    rows = (pl.program_id(1) * block_rows
+            + jax.lax.broadcasted_iota(jnp.int32, (block_rows, LANES), 0))
+    accumulate_lanes(acc_ref, fp_ref, cur * row_weights_i32(rows), n_blocks)
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
@@ -104,23 +98,22 @@ def xor_fp_lanes(cur_words, par_words, *, block_rows: int = 256,
     xor words ``[C, R, 128]``)."""
     C, R, L = cur_words.shape
     assert L == LANES and par_words.shape == cur_words.shape
-    block_rows = _fit_rows(R, block_rows)
-    nb = R // block_rows
-    spec = pl.BlockSpec((1, block_rows, LANES), lambda c, j: (c, j, 0))
+    br, Rp = row_plan(R, block_rows)
+    nb = Rp // br
+    spec = pl.BlockSpec((None, br, LANES), lambda c, j: (c, j, 0))
     lanes, xor = pl.pallas_call(
-        functools.partial(_xor_fp_kernel, block_rows=block_rows,
-                          n_blocks=nb),
+        functools.partial(_xor_fp_kernel, block_rows=br, n_blocks=nb),
         grid=(C, nb),
         in_specs=[spec, spec],
-        out_specs=[pl.BlockSpec((1, LANES), lambda c, j: (c, 0)), spec],
-        out_shape=[jax.ShapeDtypeStruct((C, LANES), jnp.uint32),
-                   jax.ShapeDtypeStruct((C, R, LANES), jnp.uint32)],
-        scratch_shapes=[pltpu.VMEM((1, LANES), jnp.uint32)],
-        compiler_params=_CompilerParams(
+        out_specs=[LANES_SPEC, spec],
+        out_shape=[jax.ShapeDtypeStruct((C, 1, LANES), jnp.int32),
+                   jax.ShapeDtypeStruct((C, Rp, LANES), jnp.int32)],
+        scratch_shapes=[pltpu.VMEM((1, LANES), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(cur_words, par_words)
-    return lanes, xor
+    )(as_i32(pad_rows(cur_words, Rp)), as_i32(pad_rows(par_words, Rp)))
+    return as_u32(lanes[:, 0]), as_u32(xor[:, :R])
 
 
 def xor_fp_ref(cur_words, par_words):
@@ -134,7 +127,7 @@ def xor_fp_ref(cur_words, par_words):
 
 def _quant_blocks(delta_blocks):
     """``optim.compression._quant`` core on ``[NB, 256]`` f32 blocks ->
-    (q int32 ``[NB, 256]``, scale f32 ``[NB]``).  The scale uses the
+    (q int32 ``[NB, 256]``, scale f32 ``[NB, 1]``).  The scale uses the
     same jit-stable reciprocal-multiply expression as the host quantizer
     (see ``optim.compression._INV127``) so eager host, interpret and
     compiled kernels agree bit-exactly."""
@@ -143,69 +136,80 @@ def _quant_blocks(delta_blocks):
     scale = jnp.max(jnp.abs(delta_blocks), axis=1, keepdims=True) * _INV127
     scale = jnp.maximum(scale, 1e-12)
     q = jnp.clip(jnp.round(delta_blocks / scale), -127, 127)
-    return q.astype(jnp.int32), scale[:, 0].astype(jnp.float32)
+    return q.astype(jnp.int32), scale.astype(jnp.float32)
+
+
+def _column_to_row(col):
+    """``[n, 1]`` -> ``[1, n]`` without a relayout: each lane of the row
+    sums its diagonal entry and zeros, so the values are exact."""
+    n = col.shape[0]
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (n, n), 1))
+    return jnp.sum(jnp.where(eye, col, 0.0), axis=0, keepdims=True)
 
 
 def _int8_fp_kernel(cur_ref, par_ref, fp_ref, q_ref, scale_ref, acc_ref, *,
-                    block_rows: int, n_blocks: int):
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    cur = cur_ref[0]
+                    block_qb: int, n_blocks: int):
+    # one row per 256-float quant block: word row 2b in lanes [0, 128),
+    # word row 2b + 1 in lanes [128, 256)
+    cur = cur_ref[...]
     # quantize the float view; fingerprint the raw word view of the same
     # VMEM block — the fusion that saves the second pass over the state
     delta = (jax.lax.bitcast_convert_type(cur, jnp.float32)
-             - jax.lax.bitcast_convert_type(par_ref[0], jnp.float32))
-    q, scale = _quant_blocks(delta.reshape(block_rows // _QROWS, QBLOCK))
-    q_ref[0] = q
-    scale_ref[0] = scale
-    row0 = (j * block_rows).astype(jnp.uint32)
-    weighted = cur * _row_weights(row0, block_rows)
-    acc_ref[0] = acc_ref[0] + jnp.sum(weighted, axis=0, dtype=jnp.uint32)
-
-    @pl.when(j == n_blocks - 1)
-    def _done():
-        fp_ref[...] = acc_ref[...]
+             - jax.lax.bitcast_convert_type(par_ref[...], jnp.float32))
+    q, scale = _quant_blocks(delta)
+    q_ref[...] = q
+    scale_ref[...] = _column_to_row(scale)
+    shape = (block_qb, QBLOCK)
+    rows = (_QROWS * (pl.program_id(1) * block_qb
+                      + jax.lax.broadcasted_iota(jnp.int32, shape, 0))
+            + jax.lax.broadcasted_iota(jnp.int32, shape, 1) // LANES)
+    weighted = cur * row_weights_i32(rows)
+    accumulate_lanes(acc_ref, fp_ref,
+                     weighted[:, :LANES] + weighted[:, LANES:], n_blocks)
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def int8_fp_lanes(cur_words, par_words, *, block_rows: int = 256,
                   interpret: bool = False):
     """Fused pass: ``[C, R, 128]`` u32 x2 (R even) -> (fp lanes
-    ``[C, 128]``, q int32 ``[C, R//2, 256]``, scale f32 ``[C, R//2]``)."""
+    ``[C, 128]``, q int32 ``[C, R//2, 256]``, scale f32 ``[C, R//2]``).
+
+    The kernel sees each chunk as ``[R//2, 256]`` (one quant block per
+    row, a free reshape) and writes the scales lane-dense as
+    ``[C, 1, R//2]``; quant-block tiles are multiples of 128 so that both
+    the q and the scale blocks meet the (8, 128) rule."""
     C, R, L = cur_words.shape
     assert L == LANES and R % _QROWS == 0, cur_words.shape
     assert par_words.shape == cur_words.shape
-    block_rows = _fit_rows(R, block_rows)
-    if block_rows % _QROWS:  # quant blocks may not straddle grid steps
-        block_rows *= _QROWS
-    nb = R // block_rows
-    nblk = block_rows // _QROWS
-    spec = pl.BlockSpec((1, block_rows, LANES), lambda c, j: (c, j, 0))
+    NB = R // _QROWS
+    bq, NBp = row_plan(NB, block_rows // _QROWS, tile=LANES)
+    nb = NBp // bq
+
+    def blocks(words):
+        return as_i32(pad_rows(words.reshape(C, NB, QBLOCK), NBp))
+
+    spec = pl.BlockSpec((None, bq, QBLOCK), lambda c, j: (c, j, 0))
     lanes, q, scale = pl.pallas_call(
-        functools.partial(_int8_fp_kernel, block_rows=block_rows,
-                          n_blocks=nb),
+        functools.partial(_int8_fp_kernel, block_qb=bq, n_blocks=nb),
         grid=(C, nb),
         in_specs=[spec, spec],
         out_specs=[
-            pl.BlockSpec((1, LANES), lambda c, j: (c, 0)),
-            pl.BlockSpec((1, nblk, QBLOCK), lambda c, j: (c, j, 0)),
-            pl.BlockSpec((1, nblk), lambda c, j: (c, j)),
+            LANES_SPEC,
+            spec,
+            pl.BlockSpec((None, 1, bq), lambda c, j: (c, 0, j)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((C, LANES), jnp.uint32),
-            jax.ShapeDtypeStruct((C, R // _QROWS, QBLOCK), jnp.int32),
-            jax.ShapeDtypeStruct((C, R // _QROWS), jnp.float32),
+            jax.ShapeDtypeStruct((C, 1, LANES), jnp.int32),
+            jax.ShapeDtypeStruct((C, NBp, QBLOCK), jnp.int32),
+            jax.ShapeDtypeStruct((C, 1, NBp), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((1, LANES), jnp.uint32)],
-        compiler_params=_CompilerParams(
+        scratch_shapes=[pltpu.VMEM((1, LANES), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(cur_words, par_words)
-    return lanes, q, scale
+    )(blocks(cur_words), blocks(par_words))
+    return as_u32(lanes[:, 0]), q[:, :NB], scale[:, 0, :NB]
 
 
 def int8_fp_ref(cur_words, par_words):

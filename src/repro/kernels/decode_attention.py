@@ -20,20 +20,17 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import (CompilerParams as _CompilerParams,
-                                         MemorySpace as _MemorySpace)
-
 from repro.kernels.ref import NEG_INF
 
 
 def _decode_kernel(
-    qpos_ref,  # SMEM [1] current position (per batch row)
-    q_ref,  # [1, H, D] (one batch row, all heads)
-    k_ref, v_ref,  # [1, bk, Hkv, D]
+    qpos_ref,  # SMEM [B] current position of each batch row
+    q_ref,  # [H, Hkv*D] block-diagonal query (one batch row)
+    k_ref, v_ref,  # [bk, Hkv*D] (kv heads flattened into lanes)
     kpos_ref,  # [1, bk] slot positions (-1 = empty)
-    o_ref,  # [1, H, D]
-    acc_ref, m_ref, l_ref,  # VMEM scratch [H, D], [H, 128], [H, 128]
-    *, block_k: int, kv_steps: int, g: int, sm_scale: float,
+    o_ref,  # [H, Hkv*D]
+    acc_ref, m_ref, l_ref,  # VMEM scratch [H, Hkv*D], [H, 128], [H, 128]
+    *, kv_steps: int, sm_scale: float,
 ):
     ik = pl.program_id(1)
 
@@ -43,21 +40,17 @@ def _decode_kernel(
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0].astype(jnp.float32) * sm_scale  # [H, D]
-    k = k_ref[0].astype(jnp.float32)  # [bk, Hkv, D]
-    v = v_ref[0].astype(jnp.float32)
-    H = q.shape[0]
-    Hkv = k.shape[1]
-    # GQA: repeat kv heads across the query-head group
-    kh = jnp.repeat(k.transpose(1, 0, 2), g, axis=0)  # [H, bk, D]
-    vh = jnp.repeat(v.transpose(1, 0, 2), g, axis=0)
+    q = q_ref[...].astype(jnp.float32) * sm_scale
+    k = k_ref[...].astype(jnp.float32)
+    v = v_ref[...].astype(jnp.float32)
+    # q is zero outside each head's own kv group, so one 2-D matmul over
+    # the flattened kv lanes gives every head's scores against its group
     s = jax.lax.dot_general(
-        q[:, None, :], kh, (((2,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    )[:, 0, :]  # [H, bk]
-    kpos = kpos_ref[0]  # [bk]
+        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    )  # [H, bk]
+    kpos = kpos_ref[...]  # [1, bk]
     valid = (kpos >= 0) & (kpos <= qpos_ref[pl.program_id(0)])
-    s = jnp.where(valid[None, :], s, NEG_INF)
+    s = jnp.where(valid, s, NEG_INF)
     m_prev = m_ref[:, 0]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
     p = jnp.exp(s - m_new[:, None])
@@ -65,21 +58,26 @@ def _decode_kernel(
     l_ref[:, 0] = l_ref[:, 0] * corr + jnp.sum(p, axis=-1)
     m_ref[:, 0] = m_new
     pv = jax.lax.dot_general(
-        p[:, None, :], vh, (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    )[:, 0, :]  # [H, D]
+        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+    )  # [H, Hkv*D]: head h's output is its own group's D lanes
     acc_ref[...] = acc_ref[...] * corr[:, None] + pv
 
     @pl.when(ik == kv_steps - 1)
     def _finish():
         l = jnp.maximum(l_ref[:, 0], 1e-37)
-        o_ref[0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block_k", "interpret"))
 def decode_attention(q, k_cache, v_cache, q_pos, k_pos, *, block_k: int = 512,
                      interpret: bool = False):
-    """q [B,1,H,D]; caches [B,S,Hkv,D]; q_pos [B]; k_pos [B,S] -> [B,1,H,D]."""
+    """q [B,1,H,D]; caches [B,S,Hkv,D]; q_pos [B]; k_pos [B,S] -> [B,1,H,D].
+
+    The caches enter as ``[B, S, Hkv*D]`` and ``k_pos`` as ``[B, 1, S]``
+    (free reshapes), so every block's last two dims are ``(block_k, full)``
+    or ``(1, block_k)``: legal on TPU for any batch when ``block_k`` is a
+    multiple of 128 or all of ``S``.  GQA runs as 2-D matmuls against a
+    block-diagonal query; the wrapper keeps each head's own group."""
     B, _, H, D = q.shape
     S, Hkv = k_cache.shape[1], k_cache.shape[2]
     g = H // Hkv
@@ -87,28 +85,34 @@ def decode_attention(q, k_cache, v_cache, q_pos, k_pos, *, block_k: int = 512,
     assert S % block_k == 0
     nk = S // block_k
     sm_scale = float(1.0 / (D ** 0.5))
+    group = jnp.arange(H) // g
+    own = (group[:, None] == jnp.arange(Hkv)[None, :]).astype(q.dtype)
+    q_bd = (q[:, 0, :, None, :] * own[None, :, :, None]).reshape(
+        B, H, Hkv * D)
+    kv_spec = pl.BlockSpec((None, block_k, Hkv * D), lambda b, ik: (b, ik, 0))
+    row_spec = pl.BlockSpec((None, H, Hkv * D), lambda b, ik: (b, 0, 0))
     out = pl.pallas_call(
-        functools.partial(
-            _decode_kernel, block_k=block_k, kv_steps=nk, g=g, sm_scale=sm_scale
-        ),
+        functools.partial(_decode_kernel, kv_steps=nk, sm_scale=sm_scale),
         grid=(B, nk),
         in_specs=[
-            pl.BlockSpec(memory_space=_MemorySpace.SMEM),
-            pl.BlockSpec((1, H, D), lambda b, ik: (b, 0, 0)),
-            pl.BlockSpec((1, block_k, Hkv, D), lambda b, ik: (b, ik, 0, 0)),
-            pl.BlockSpec((1, block_k, Hkv, D), lambda b, ik: (b, ik, 0, 0)),
-            pl.BlockSpec((1, block_k), lambda b, ik: (b, ik)),
+            pl.BlockSpec(memory_space=pltpu.MemorySpace.SMEM),
+            row_spec,
+            kv_spec,
+            kv_spec,
+            pl.BlockSpec((None, 1, block_k), lambda b, ik: (b, 0, ik)),
         ],
-        out_specs=pl.BlockSpec((1, H, D), lambda b, ik: (b, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
+        out_specs=row_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, Hkv * D), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((H, D), jnp.float32),
+            pltpu.VMEM((H, Hkv * D), jnp.float32),
             pltpu.VMEM((H, 128), jnp.float32),
             pltpu.VMEM((H, 128), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
-    )(q_pos.astype(jnp.int32), q[:, 0], k_cache, v_cache, k_pos.astype(jnp.int32))
+    )(q_pos.astype(jnp.int32), q_bd, k_cache.reshape(B, S, Hkv * D),
+      v_cache.reshape(B, S, Hkv * D), k_pos.astype(jnp.int32).reshape(B, 1, S))
+    out = out.reshape(B, H, Hkv, D)[:, jnp.arange(H), group]  # [B, H, D]
     return out[:, None]

@@ -9,7 +9,8 @@ fingerprint comparison: only chunks whose fingerprint changed are
 serialized, encoded and hashed on host.
 
 Construction (all arithmetic uint32, wrap-around mod 2^32, so the Pallas
-kernel, the blockwise jnp lowering and interpret mode agree bit-exactly):
+kernel, the blockwise jnp lowering and interpret mode agree bit-exactly;
+the kernel carries the same bits as int32, see ``_mix32_i32``):
 
   * a leaf's raw bytes are reinterpreted as uint32 words and laid out as
     ``[n_chunks, rows, 128]`` (128 = TPU lane width; rows stream through
@@ -35,8 +36,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams as _CompilerParams
-
 LANES = 128          # TPU lane width; stage-1 fingerprint width
 FP_WORDS = 4         # final fingerprint words per chunk (4 x u32 = 128 bit)
 _GOLD = 0x9E3779B1   # 2^32 / golden ratio (Weyl constant)
@@ -53,56 +52,115 @@ def _mix32(x):
     return x
 
 
-def _row_weights(row0, block_rows: int):
-    """Per-row odd weights for absolute rows [row0, row0 + block_rows)."""
-    r = jax.lax.broadcasted_iota(jnp.uint32, (block_rows, LANES), 0)
-    r = r + jnp.uint32(1) + row0
-    return _mix32(r * jnp.uint32(_GOLD)) | jnp.uint32(1)
+def _i32(c: int) -> int:
+    """A uint32 constant as the int32 with the same bits."""
+    return c - (1 << 32) if c >= (1 << 31) else c
 
 
-def _fp_kernel(w_ref, out_ref, acc_ref, *, block_rows: int, n_blocks: int):
+def _mix32_i32(x):
+    """``_mix32`` on the int32 view of the same bits.  Mosaic implements
+    no reductions over unsigned integers, so the kernels carry every word
+    as int32: add, multiply and xor wrap to the same 32 bits, and the
+    shifts are logical, so results bitcast back to exactly ``_mix32``'s."""
+    srl = jax.lax.shift_right_logical
+    x = x ^ srl(x, jnp.int32(16))
+    x = x * jnp.int32(_i32(0x7FEB352D))
+    x = x ^ srl(x, jnp.int32(15))
+    x = x * jnp.int32(_i32(0x846CA68B))
+    x = x ^ srl(x, jnp.int32(16))
+    return x
+
+
+def row_weights_i32(rows):
+    """Per-row odd weights (int32 bits) for absolute 0-based row ids."""
+    r = (rows + 1) * jnp.int32(_i32(_GOLD))
+    return _mix32_i32(r) | jnp.int32(1)
+
+
+def row_plan(rows: int, want: int, tile: int = 8):
+    """-> (block_rows, padded_rows) for a row axis streamed through VMEM.
+
+    A block is the whole axis when it fits in ``want`` rows; otherwise a
+    multiple of ``tile`` (the TPU's (8, 128) block rule) that divides the
+    axis once it is zero-padded up to the tile.  Zero rows add
+    ``weight * 0`` to every fingerprint lane, so padding never changes a
+    fingerprint; callers slice padded outputs away."""
+    if rows <= want:
+        return rows, rows
+    padded = -(-rows // tile) * tile
+    block = max(tile, want - want % tile)
+    while padded % block:
+        block -= tile
+    return block, padded
+
+
+def as_i32(words):
+    return jax.lax.bitcast_convert_type(words, jnp.int32)
+
+
+def as_u32(words):
+    return jax.lax.bitcast_convert_type(words, jnp.uint32)
+
+
+def pad_rows(words, padded: int):
+    R = words.shape[1]
+    if padded == R:
+        return words
+    return jnp.pad(words, ((0, 0), (0, padded - R), (0, 0)))
+
+
+def accumulate_lanes(acc_ref, out_ref, weighted, n_blocks: int):
+    """Add a block's row sums into the lane accumulator; emit on the last
+    row block of the chunk."""
     j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    row0 = (j * block_rows).astype(jnp.uint32)
-    weighted = w_ref[0] * _row_weights(row0, block_rows)
-    acc_ref[0] = acc_ref[0] + jnp.sum(weighted, axis=0, dtype=jnp.uint32)
+    acc_ref[...] += jnp.sum(weighted, axis=0, keepdims=True)
 
     @pl.when(j == n_blocks - 1)
     def _done():
         out_ref[...] = acc_ref[...]
 
 
-def _fit_rows(rows: int, want: int) -> int:
-    b = max(min(want, rows), 1)
-    while rows % b:
-        b -= 1
-    return b
+def _fp_kernel(w_ref, out_ref, acc_ref, *, block_rows: int, n_blocks: int):
+    rows = (pl.program_id(1) * block_rows
+            + jax.lax.broadcasted_iota(jnp.int32, (block_rows, LANES), 0))
+    accumulate_lanes(acc_ref, out_ref, w_ref[...] * row_weights_i32(rows),
+                     n_blocks)
+
+
+LANES_SPEC = pl.BlockSpec((None, 1, LANES), lambda c, j: (c, 0, 0))
+"""One chunk's ``[1, 128]`` lane row of a ``[C, 1, 128]`` output: the
+last two block dims equal the array's, which the TPU's block rule
+accepts for any chunk count."""
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def fingerprint_lanes(words, *, block_rows: int = 256,
                       interpret: bool = False):
-    """Stage 1 on Pallas: ``[C, R, 128]`` uint32 -> ``[C, 128]`` uint32."""
+    """Stage 1 on Pallas: ``[C, R, 128]`` uint32 -> ``[C, 128]`` uint32.
+
+    Grid (chunk, row block); a chunk's rows stream through VMEM in blocks
+    of ``row_plan(R, block_rows)`` rows."""
     C, R, L = words.shape
     assert L == LANES, words.shape
-    block_rows = _fit_rows(R, block_rows)
-    nb = R // block_rows
-    return pl.pallas_call(
-        functools.partial(_fp_kernel, block_rows=block_rows, n_blocks=nb),
+    br, Rp = row_plan(R, block_rows)
+    nb = Rp // br
+    lanes = pl.pallas_call(
+        functools.partial(_fp_kernel, block_rows=br, n_blocks=nb),
         grid=(C, nb),
-        in_specs=[pl.BlockSpec((1, block_rows, LANES),
-                               lambda c, j: (c, j, 0))],
-        out_specs=pl.BlockSpec((1, LANES), lambda c, j: (c, 0)),
-        out_shape=jax.ShapeDtypeStruct((C, LANES), jnp.uint32),
-        scratch_shapes=[pltpu.VMEM((1, LANES), jnp.uint32)],
-        compiler_params=_CompilerParams(
+        in_specs=[pl.BlockSpec((None, br, LANES), lambda c, j: (c, j, 0))],
+        out_specs=LANES_SPEC,
+        out_shape=jax.ShapeDtypeStruct((C, 1, LANES), jnp.int32),
+        scratch_shapes=[pltpu.VMEM((1, LANES), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(words)
+    )(as_i32(pad_rows(words, Rp)))
+    return as_u32(lanes[:, 0])
 
 
 def fingerprint_lanes_ref(words):

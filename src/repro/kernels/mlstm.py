@@ -28,8 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams as _CompilerParams
-
 from repro.kernels.ref import NEG_INF
 
 
@@ -136,7 +134,7 @@ def mlstm(q, k, v, i_gate, f_gate, *, chunk: int = 128,
             pltpu.VMEM((1, D), jnp.float32),
             pltpu.VMEM((1, 128), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

@@ -19,8 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams as _CompilerParams
-
 
 def _rglru_kernel(
     x_ref, ga_ref, gx_ref, a_ref,  # [1, T, Wb], [1, T, Wb], [1, T, Wb], [1, Wb]
@@ -91,7 +89,7 @@ def rglru(x, a_param, gate_a, gate_x, h0=None, *, c: float = 8.0,
             jax.ShapeDtypeStruct((B, W), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((1, block_w), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

@@ -18,8 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams as _CompilerParams
-
 
 def _slstm_kernel(
     xi_ref, xf_ref, xz_ref, xo_ref,  # [1, T, 1, hb]
@@ -91,7 +89,7 @@ def slstm(x_i, x_f, x_z, x_o, r_i, r_f, r_z, r_o, *, chunk: int = 128,
         out_specs=pl.BlockSpec((1, chunk, 1, hb), lambda b, h, c: (b, c, h, 0)),
         out_shape=jax.ShapeDtypeStruct((B, S, H, hb), x_i.dtype),
         scratch_shapes=[pltpu.VMEM((1, hb), jnp.float32) for _ in range(4)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

@@ -1,12 +1,14 @@
 """Migration driver CLI — the Migration Manager as an operator command.
 
   PYTHONPATH=src python -m repro.launch.migrate \
-      --strategy ms2m_cutoff --rate 12 --arch paper_consumer \
-      --batched-replay --registry /tmp/reg
+      --strategy ms2m_cutoff --rate 12 --batched-replay --registry /tmp/reg
 
 Runs the full workload (producer -> consumer pod -> migration -> verify)
-on the virtual-time cluster with a real JAX consumer and prints the
-MigrationReport (phases, downtime, image bytes, verification).
+on the virtual-time cluster with a real JAX consumer (the
+``paper_consumer`` model) and prints the MigrationReport (phases,
+downtime, image bytes, verification).  The summary line also counts the
+leaves each push delta-encoded with the fused device kernels
+(``fused_leaves``) and with the host codecs (``host_codec_leaves``).
 
 ``--workload serving`` switches to the serving harness instead: an
 open-loop Poisson *request* stream (``--rate`` in req/s) against a
@@ -35,6 +37,7 @@ from repro.core import (
     registry_entries,
     run_migration_experiment,
 )
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def list_topologies() -> int:
@@ -55,6 +58,11 @@ def list_strategies() -> int:
         print(f"{row['name']:20s} [{', '.join(flags) or '-'}]")
         print(f"    {row['summary']}")
     return 0
+
+
+def _codec_leaves(report) -> str:
+    return (f" fused_leaves={report.fused_leaves}"
+            f" host_codec_leaves={report.host_codec_leaves}")
 
 
 def main(argv=None) -> int:
@@ -159,6 +167,7 @@ def main(argv=None) -> int:
         return list_strategies()
     if args.list_topologies:
         return list_topologies()
+    enable_compile_cache()
 
     if args.workload == "rebalance":
         from repro.cluster.controller import (RebalanceConfig,
@@ -223,7 +232,8 @@ def main(argv=None) -> int:
         print(f"[migrate] p50={lat['p50']} p99={lat['p99']} "
               f"p999={lat['p999']} downtime={r.downtime:.2f}s "
               f"exactly_once={r.exactly_once} "
-              f"state_verified={r.state_verified}")
+              f"state_verified={r.state_verified}"
+              + (_codec_leaves(r.report) if r.report is not None else ""))
         return 0 if r.exactly_once and r.state_verified is not False else 1
 
     worker_factory = None
@@ -265,7 +275,7 @@ def main(argv=None) -> int:
         print(json.dumps(r.report.event_rows(), indent=2))
     print(f"[migrate] downtime={r.downtime:.2f}s "
           f"migration={r.migration_time:.2f}s verified={r.verified} "
-          f"attempts={r.report.attempts}")
+          f"attempts={r.report.attempts}" + _codec_leaves(r.report))
     return 0 if r.verified else 1
 
 

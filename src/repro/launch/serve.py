@@ -1,5 +1,7 @@
 """Serving driver: batched decode with KV-cache management — the worker
-type that MS2M migrates.  Runs for real with a reduced config on this host.
+type that MS2M migrates.  ``--smoke`` takes the reduced config, which runs
+on a CPU; without it the published config runs, which wants a TPU.
+Exits 1 if any prefill or decode logit is not finite.
 
 Usage:
   PYTHONPATH=src python -m repro.launch.serve --arch smollm_360m --smoke \
@@ -15,6 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import configs
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as T
 from repro.train import step as steplib
 
@@ -28,6 +31,7 @@ def main(argv=None) -> int:
     ap.add_argument("--decode-steps", type=int, default=32)
     ap.add_argument("--max-seq", type=int, default=128)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = configs.get_smoke(args.arch) if args.smoke else configs.get_config(args.arch)
     params = T.init_lm(jax.random.PRNGKey(0), cfg)
@@ -51,6 +55,7 @@ def main(argv=None) -> int:
     logits, cache = prefill(params, cache, batch)
     jax.block_until_ready(logits)
     t_prefill = time.perf_counter() - t0
+    finite = jnp.isfinite(logits).all()
     print(f"[serve] prefill {args.prompt_len} tokens x {B} requests: "
           f"{t_prefill*1e3:.0f}ms")
 
@@ -60,6 +65,7 @@ def main(argv=None) -> int:
     t0 = time.perf_counter()
     for i in range(args.decode_steps):
         logits, cache = decode(params, cache, tok, pos)
+        finite &= jnp.isfinite(logits).all()
         tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         pos = pos + 1
         generated.append(tok)
@@ -70,6 +76,9 @@ def main(argv=None) -> int:
           f"{dt*1e3:.0f}ms ({toks_s:.0f} tok/s)")
     out = jnp.concatenate(generated, axis=1)
     print(f"[serve] sample continuation (request 0): {np.asarray(out[0])[:16]}")
+    if not bool(finite):
+        print("[serve] FAIL: non-finite logits")
+        return 1
     return 0
 
 

@@ -27,6 +27,7 @@ import numpy as np
 from repro import configs
 from repro.checkpoint import Checkpointer, Registry
 from repro.data import DataConfig, SyntheticTokenDataset
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as T
 from repro.models.common import split_params
 from repro.optim import adamw
@@ -47,6 +48,7 @@ def main(argv=None) -> int:
     ap.add_argument("--lr", type=float, default=3e-3)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = configs.get_smoke(args.arch) if args.smoke else configs.get_config(args.arch)
     tcfg = steplib.TrainStepConfig(

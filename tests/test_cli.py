@@ -2,10 +2,26 @@
 parsing, listings, exit codes, and short end-to-end runs with the cheap
 hash-fold consumer."""
 import json
+import os
 
+import jax
+import jax.numpy as jnp
 import pytest
+from jax.experimental.compilation_cache import compilation_cache
 
+from repro.launch import compile_cache
 from repro.launch.migrate import main
+
+REPO = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
+
+
+@pytest.fixture(autouse=True)
+def _compile_cache_off_after():
+    """``main()`` turns on JAX's persistent compile cache for the whole
+    process; turn it off again so later tests do not write to it."""
+    yield
+    jax.config.update("jax_compilation_cache_dir", None)
+    compilation_cache.reset_cache()
 
 
 def test_list_strategies_prints_registry(capsys):
@@ -132,3 +148,33 @@ def test_serving_workload_baseline_scheme(capsys, tmp_path):
     assert rc == 0
     row = json.loads(out[:out.rindex("}") + 1])
     assert row["exactly_once"] is True and row["state_verified"] is True
+
+
+def test_compile_cache_goes_where_the_environment_places_it(monkeypatch,
+                                                           tmp_path):
+    placed = tmp_path / "placed"
+    monkeypatch.setenv(compile_cache.CACHE_ENV, str(placed))
+    fixed = compile_cache.DEFAULT_CACHE_DIR
+    before = sorted(os.listdir(fixed)) if os.path.isdir(fixed) else []
+    assert compile_cache.enable_compile_cache() == str(placed)
+    min_secs = jax.config.jax_persistent_cache_min_compile_time_secs
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    try:
+        compilation_cache.reset_cache()
+        jax.jit(lambda x: jnp.cos(x) * 5 - 2)(jnp.ones(11)).block_until_ready()
+    finally:
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          min_secs)
+    assert any(name.endswith("-cache") for name in os.listdir(placed))
+    after = sorted(os.listdir(fixed)) if os.path.isdir(fixed) else []
+    assert after == before
+
+
+def test_compile_cache_defaults_to_a_fixed_ignored_checkout_path(
+        monkeypatch):
+    monkeypatch.delenv(compile_cache.CACHE_ENV, raising=False)
+    path = compile_cache.enable_compile_cache()
+    assert path == os.path.join(REPO, ".jax_cache")
+    assert jax.config.jax_compilation_cache_dir == path
+    with open(os.path.join(REPO, ".gitignore")) as f:
+        assert "/.jax_cache/" in f.read().split()

@@ -347,23 +347,3 @@ def test_timing_constants_from_roofline_is_opt_in():
     assert TimingConstants.from_roofline(
         {"codec_Bps": 2e8, "fingerprint_Bps": 0}).fingerprint_Bps == 24e9
 
-
-# ---------------------------------------------------------------------------
-# pallas_compat shims
-# ---------------------------------------------------------------------------
-
-def test_pallas_compat_exports_usable_shims():
-    from jax.experimental.pallas import tpu as pltpu
-
-    from repro.kernels import pallas_compat
-
-    assert pallas_compat.CompilerParams in (
-        getattr(pltpu, "CompilerParams", None),
-        getattr(pltpu, "TPUCompilerParams", None))
-    assert pallas_compat.MemorySpace in (
-        getattr(pltpu, "MemorySpace", None),
-        getattr(pltpu, "TPUMemorySpace", None))
-    # the construction every kernel in this repo performs
-    params = pallas_compat.CompilerParams(
-        dimension_semantics=("parallel", "arbitrary"))
-    assert tuple(params.dimension_semantics) == ("parallel", "arbitrary")

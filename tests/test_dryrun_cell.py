@@ -14,7 +14,7 @@ def test_dryrun_single_cell(tmp_path):
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.abspath(
         os.path.join(os.path.dirname(__file__), "..", "src"))
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run(
         [sys.executable, "-m", "repro.launch.dryrun",
          "--arch", "smollm_360m", "--shape", "decode_32k",
@@ -37,7 +37,7 @@ def test_dryrun_skip_rule(tmp_path):
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.abspath(
         os.path.join(os.path.dirname(__file__), "..", "src"))
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run(
         [sys.executable, "-m", "repro.launch.dryrun",
          "--arch", "codeqwen1_5_7b", "--shape", "long_500k",
