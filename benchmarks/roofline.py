@@ -1,27 +1,14 @@
-"""Roofline tables: dry-run model terms and measured codec throughput.
+"""Roofline table of the dry-run model terms.
 
-Two entry points:
-
-  * the dry-run table (default) reads results/dryrun.json (written by
-    repro.launch.dryrun) and prints the three terms per
-    (arch x shape x mesh), the dominant bottleneck, the
-    MODEL_FLOPS/HLO_FLOPS usefulness ratio, and a one-line "what would
-    move the dominant term" suggestion;
-  * ``--codec`` measures the checkpoint data path itself — fingerprint
-    and fingerprint+encode bytes/s per chunk size, legacy two-pass flow
-    vs the fused kernel path — and writes results/codec_roofline.json
-    (schema in docs/kernels.md).  ``TimingConstants.from_roofline``
-    consumes the calibration block.  ``--devices N`` applies the
-    ``xla_force_host_platform_device_count`` idiom (must happen before
-    the first jax import, hence the lazy imports below) so multi-device
-    CPU numbers are honest about the host they ran on.
+Reads results/dryrun.json (written by repro.launch.dryrun) and prints the
+three terms per (arch x shape x mesh), the dominant bottleneck, the
+MODEL_FLOPS/HLO_FLOPS usefulness ratio, and a one-line "what would move
+the dominant term" suggestion.
 """
 from __future__ import annotations
 
 import argparse
 import json
-import os
-import time
 from typing import Dict, List
 
 SUGGESTIONS = {
@@ -55,168 +42,11 @@ def fmt_row(r: dict) -> str:
             f"useful={frac:5.2f}")
 
 
-# ---------------------------------------------------------------------------
-# measured codec roofline (the checkpoint data path itself)
-# ---------------------------------------------------------------------------
-
-CODEC_CHUNK_SIZES = (4 * 1024, 64 * 1024, 1024 * 1024)
-
-
-def configure_host_devices(n: int) -> None:
-    """Pre-jax-import platform config (SNIPPETS.md idiom): virtual CPU
-    devices only exist if the flag lands before jax initializes."""
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    if n > 1:
-        flags = os.environ.get("XLA_FLAGS", "")
-        os.environ["XLA_FLAGS"] = (
-            f"{flags} --xla_force_host_platform_device_count={n}".strip())
-
-
-def _geomean(xs: List[float]) -> float:
-    import numpy as np
-
-    return float(np.exp(np.mean(np.log(np.asarray(xs)))))
-
-
-def run_codec_roofline(chunk_sizes=CODEC_CHUNK_SIZES, leaf_mib: int = 16,
-                       repeats: int = 3, quick: bool = False,
-                       out_path: str = "results/codec_roofline.json"
-                       ) -> dict:
-    """Measure fingerprint / fingerprint+encode throughput per chunk size.
-
-    One striped-dirty f32 leaf; per chunk size, best-of-``repeats`` wall
-    time (after a warmup that absorbs jit compilation) for:
-
-      * ``fingerprint`` — the device fingerprint pass alone;
-      * ``encode_<codec>`` — the host codec encoders alone (what
-        ``TimingConstants.codec_Bps`` charges);
-      * ``fp+encode_<codec>`` twice — the legacy ``two_pass`` flow
-        (fingerprint pass, then serialize + host-encode every chunk) vs
-        the ``fused`` single-pass kernel path the registry now uses.
-
-    Returns (and writes) the result dict; the ``calibration`` block holds
-    geomean throughputs shaped for ``TimingConstants.from_roofline``.
-    """
-    import numpy as np
-
-    import jax
-
-    from repro.checkpoint.codecs import FusedLeafEncoding, get_codec
-    from repro.kernels import ops
-
-    if quick:
-        leaf_mib, repeats = 4, 1
-    rng = np.random.default_rng(0)
-    n = leaf_mib * (1 << 20) // 4
-    cur = rng.standard_normal(n).astype(np.float32)
-    parent = cur.copy()
-    idx = rng.integers(0, n, size=n // 64)
-    parent[idx] += rng.standard_normal(idx.size).astype(np.float32)
-    praw = parent.tobytes()
-    nbytes = cur.nbytes
-    dt = np.dtype(np.float32)
-
-    def bench(fn) -> float:
-        fn()  # warmup: jit compile + first-touch
-        best = float("inf")
-        for _ in range(repeats):
-            t0 = time.perf_counter()
-            fn()
-            best = min(best, time.perf_counter() - t0)
-        return best
-
-    rows: List[dict] = []
-
-    def add(op: str, path: str, cb: int, elapsed: float):
-        rows.append({"op": op, "path": path, "chunk_bytes": cb,
-                     "elapsed_s": round(elapsed, 6),
-                     "bytes_per_s": round(nbytes / elapsed, 1)})
-
-    for cb in chunk_sizes:
-        n_chunks = -(-nbytes // cb)
-
-        def fp_pass():
-            np.asarray(ops.chunk_fingerprint(cur, cb))
-
-        add("fingerprint", "device", cb, bench(fp_pass))
-        for name in ("xor_rle", "int8"):
-            codec = get_codec(name)
-            raw = cur.tobytes()
-
-            def encode_only():
-                for c in range(n_chunks):
-                    codec.encode(raw[c * cb: (c + 1) * cb],
-                                 praw[c * cb: (c + 1) * cb], dt)
-
-            def two_pass():
-                np.asarray(ops.chunk_fingerprint(cur, cb))
-                data = cur.tobytes()
-                for c in range(n_chunks):
-                    codec.encode(data[c * cb: (c + 1) * cb],
-                                 praw[c * cb: (c + 1) * cb], dt)
-
-            def fused():
-                fenc = FusedLeafEncoding(cur, praw, name, dt, cb)
-                for c in range(n_chunks):
-                    fenc.blob(c)
-
-            add(f"encode_{name}", "host", cb, bench(encode_only))
-            add(f"fp+encode_{name}", "two_pass", cb, bench(two_pass))
-            add(f"fp+encode_{name}", "fused", cb, bench(fused))
-
-    result = {
-        "backend": jax.default_backend(),
-        "device_count": jax.device_count(),
-        "leaf_bytes": nbytes,
-        "repeats": repeats,
-        "chunk_sizes": list(chunk_sizes),
-        "rows": rows,
-        "calibration": {
-            "codec_Bps": _geomean([r["bytes_per_s"] for r in rows
-                                   if r["op"].startswith("encode_")]),
-            "fingerprint_Bps": _geomean([r["bytes_per_s"] for r in rows
-                                         if r["op"] == "fingerprint"]),
-            # the cost-model defaults these would replace (see
-            # TimingConstants.from_roofline: replacing them is opt-in —
-            # regression timelines stay pinned to the defaults)
-            "defaults": {"codec_Bps": 1.2e9, "fingerprint_Bps": 24e9},
-        },
-    }
-    if out_path:
-        os.makedirs(os.path.dirname(out_path) or ".", exist_ok=True)
-        with open(out_path, "w") as f:
-            json.dump(result, f, indent=1, sort_keys=True)
-    return result
-
-
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--input", default="results/dryrun.json")
     ap.add_argument("--mesh", default="all")
-    ap.add_argument("--codec", action="store_true",
-                    help="measure the codec roofline instead of printing "
-                         "the dry-run table")
-    ap.add_argument("--devices", type=int, default=1,
-                    help="virtual CPU device count for --codec (set via "
-                         "xla_force_host_platform_device_count before "
-                         "jax loads)")
-    ap.add_argument("--quick", action="store_true")
-    ap.add_argument("--out", default="results/codec_roofline.json")
     args = ap.parse_args(argv)
-    if args.codec:
-        configure_host_devices(args.devices)
-        res = run_codec_roofline(quick=args.quick, out_path=args.out)
-        print(f"{'op':22s} {'path':9s} {'chunk':>9s} {'MB/s':>10s}")
-        for r in res["rows"]:
-            print(f"{r['op']:22s} {r['path']:9s} {r['chunk_bytes']:9d} "
-                  f"{r['bytes_per_s'] / 1e6:10.1f}")
-        cal = res["calibration"]
-        print(f"\ncalibration: codec_Bps={cal['codec_Bps']:.3g} "
-              f"fingerprint_Bps={cal['fingerprint_Bps']:.3g} "
-              f"(defaults {cal['defaults']['codec_Bps']:.3g}/"
-              f"{cal['defaults']['fingerprint_Bps']:.3g}) "
-              f"-> {args.out}")
-        return 0
     rows = load(args.input)
     if args.mesh != "all":
         rows = [r for r in rows if r.get("mesh") == args.mesh]

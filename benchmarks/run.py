@@ -90,23 +90,6 @@ def main(argv=None) -> int:
     _csv("claims/validated", time.time() - t, f"{npass}/{len(claims)} bands pass")
     print(f"# claims done in {time.time()-t:.1f}s", file=sys.stderr)
 
-    t = time.time()
-    # codec roofline: measured fingerprint+encode bytes/s per chunk size,
-    # two-pass host flow vs the fused kernel path (also in --quick so CI
-    # uploads codec_roofline.json; quick = smaller leaf, 1 repeat)
-    from benchmarks.roofline import run_codec_roofline
-    cr = run_codec_roofline(quick=args.quick,
-                            out_path="results/codec_roofline.json")
-    for r in cr["rows"]:
-        if r["op"].startswith("fp+encode"):
-            _csv(f"roofline/{r['op']}@{r['chunk_bytes']}_{r['path']}",
-                 r["elapsed_s"], f"{r['bytes_per_s'] / 1e6:.0f}MB/s")
-    cal = cr["calibration"]
-    _csv("roofline/calibration", time.time() - t,
-         f"codec_Bps={cal['codec_Bps']:.3g} "
-         f"fingerprint_Bps={cal['fingerprint_Bps']:.3g}")
-    print(f"# codec_roofline done in {time.time()-t:.1f}s", file=sys.stderr)
-
     if not args.quick:
         t = time.time()
         from benchmarks.beyond_paper import (run_batched_replay_bench,

@@ -33,6 +33,8 @@ from typing import Dict, Optional, Union
 
 import numpy as np
 
+from repro import obs
+
 COMPRESSION_CHOICES = ("none", "xor_rle", "int8", "auto")
 
 # Encoder backend for parent-relative codecs.  "kernel" (the default)
@@ -261,12 +263,15 @@ class FusedLeafEncoding:
             fps, xor = ops.fused_xor_fingerprint(leaf, parent_buf,
                                                  chunk_bytes)
             self._xor = np.asarray(xor)          # [C, R, 128] u32
+            down = self._xor.nbytes
         else:
             fps, q, scale = ops.fused_int8_fingerprint(leaf, parent_buf,
                                                        chunk_bytes)
             self._q = np.asarray(q)              # [C, NB, 256] i32
             self._scale = np.asarray(scale)      # [C, NB] f32
+            down = self._q.nbytes + self._scale.nbytes
         self.fps = np.asarray(fps)               # [C, 4] u32
+        obs.count("push.d2h_bytes", down + self.fps.nbytes)
 
     def _seg_len(self, c: int) -> int:
         return min(self._cb, self._nbytes - c * self._cb)
@@ -274,7 +279,10 @@ class FusedLeafEncoding:
     def raw_seg(self, c: int) -> bytes:
         """Raw bytes of chunk ``c`` (lazy leaf serialization, memoized)."""
         if self._raw is None:
-            self._raw = np.asarray(self._leaf).tobytes()
+            with obs.span("push.serialize"):
+                if not isinstance(self._leaf, np.ndarray):
+                    obs.count("push.d2h_bytes", self._leaf.nbytes)
+                self._raw = np.asarray(self._leaf).tobytes()
         return self._raw[c * self._cb: c * self._cb + self._cb]
 
     def blob(self, c: int) -> bytes:

@@ -57,6 +57,7 @@ import numpy as np
 
 import jax
 
+from repro import obs
 from repro.checkpoint import codecs as _codecs
 from repro.checkpoint import fingerprint as _fingerprint
 from repro.checkpoint.fingerprint import leaf_fingerprints
@@ -148,7 +149,16 @@ def _leaf_meta(leaf) -> Tuple[str, Tuple[int, ...], int]:
 def _leaf_raw(leaf) -> bytes:
     """C-order raw bytes of the leaf (device->host transfer happens here,
     and only for leaves with at least one dirty chunk)."""
-    return np.asarray(leaf).tobytes()
+    with obs.span("push.serialize"):
+        if isinstance(leaf, jax.Array):
+            obs.count("push.d2h_bytes", leaf.nbytes)
+        return np.asarray(leaf).tobytes()
+
+
+def _host_nbytes(leaf) -> int:
+    """Bytes the device kernels must be handed for this leaf: none for a
+    leaf already on the device."""
+    return 0 if isinstance(leaf, jax.Array) else int(np.asarray(leaf).nbytes)
 
 
 class Registry:
@@ -204,12 +214,16 @@ class Registry:
         arr = _fingerprint.normalize_leaf(leaf)
         if arr is None or arr.size == 0:
             return None
-        parent_buf = b"".join(
-            self._chunk_raw(parent, name, i, c, memo=memo)
-            for c in range(n))
-        return _codecs.FusedLeafEncoding(arr, parent_buf, codec_name,
-                                         _resolve_dtype(dtype),
-                                         self.chunk_bytes)
+        with obs.span("push.parent"):
+            parent_buf = b"".join(
+                self._chunk_raw(parent, name, i, c, memo=memo)
+                for c in range(n))
+        obs.count("push.parent_bytes", len(parent_buf))
+        obs.count("push.h2d_bytes", len(parent_buf) + _host_nbytes(arr))
+        with obs.span("push.device"):
+            return _codecs.FusedLeafEncoding(arr, parent_buf, codec_name,
+                                             _resolve_dtype(dtype),
+                                             self.chunk_bytes)
 
     def _push(self, trees: Dict[str, Any], meta: Optional[dict],
               tag: Optional[str], parent: Optional[str], *,
@@ -248,8 +262,13 @@ class Registry:
                     fps = fenc.fps
                 else:
                     host_codec += codec_name != "none"
-                    fps = (leaf_fingerprints(leaf, cb)
-                           if fingerprints else None)
+                    fps = None
+                    if fingerprints:
+                        with obs.span("push.device"):
+                            fps = leaf_fingerprints(leaf, cb)
+                    if fps is not None:
+                        obs.count("push.h2d_bytes", _host_nbytes(leaf))
+                        obs.count("push.d2h_bytes", fps.nbytes)
                 if fps is not None:
                     fp_bytes += nbytes
                 fp_list = (None if fps is None
@@ -289,14 +308,19 @@ class Registry:
                             blob = data[c * cb: (c + 1) * cb]
                         else:
                             if fenc is not None:
-                                blob = fenc.blob(c)
+                                with obs.span("push.rle"):
+                                    blob = fenc.blob(c)
                             else:
-                                parent_raw = self._chunk_raw(
-                                    parent, name, i, c,
-                                    memo=parent_raw_memo)
-                                blob = codec.encode(
-                                    data[c * cb: (c + 1) * cb],
-                                    parent_raw, _resolve_dtype(dtype))
+                                with obs.span("push.parent"):
+                                    parent_raw = self._chunk_raw(
+                                        parent, name, i, c,
+                                        memo=parent_raw_memo)
+                                obs.count("push.parent_bytes",
+                                          len(parent_raw))
+                                with obs.span("push.rle"):
+                                    blob = codec.encode(
+                                        data[c * cb: (c + 1) * cb],
+                                        parent_raw, _resolve_dtype(dtype))
                             enc_raw += seg_len
                             if len(blob) >= seg_len:
                                 # incompressible: store raw
@@ -312,7 +336,9 @@ class Registry:
                                     # fingerprint would misrepresent it
                                     if fp_list is not None:
                                         fp_list[c] = None
-                        key, new = self.store.put(blob)
+                        with obs.span("push.store"):
+                            key, new = self.store.put(blob)
+                        obs.count("push.hashed_bytes", len(blob))
                         entry["key"] = key
                         entry["wire"] = len(blob)
                         if new:
@@ -330,13 +356,14 @@ class Registry:
                 "treedef": pickle.dumps(treedef).hex(),
                 "leaves": leaf_entries,
             }
-        blob = json.dumps(manifest, sort_keys=True).encode()
-        image_id = hashlib.sha256(blob).hexdigest()[:24]
-        path = os.path.join(self.root, "manifests", image_id + ".json")
-        if not os.path.exists(path):
-            with open(path + ".tmp", "wb") as f:
-                f.write(blob)
-            os.replace(path + ".tmp", path)
+        with obs.span("push.manifest"):
+            blob = json.dumps(manifest, sort_keys=True).encode()
+            image_id = hashlib.sha256(blob).hexdigest()[:24]
+            path = os.path.join(self.root, "manifests", image_id + ".json")
+            if not os.path.exists(path):
+                with open(path + ".tmp", "wb") as f:
+                    f.write(blob)
+                os.replace(path + ".tmp", path)
         if tag:
             with self._lock:
                 self._tags[tag] = image_id
@@ -445,6 +472,7 @@ class Registry:
 
         def charge(key: str, wire: int):
             nonlocal pulled
+            obs.count("pull.read_bytes", wire)
             if key not in seen:
                 pulled += wire
                 seen.add(key)
@@ -453,11 +481,14 @@ class Registry:
             treedef = pickle.loads(bytes.fromhex(spec["treedef"]))
             leaves = []
             for li, entry in enumerate(spec["leaves"]):
-                data = b"".join(
-                    self._chunk_raw(image_id, name, li, ci, charge, memo)
-                    for ci in range(len(entry["chunks"])))
-                arr = np.frombuffer(data, dtype=_resolve_dtype(entry["dtype"]))
-                leaves.append(arr.reshape(entry["shape"]).copy())
+                with obs.span("pull.decode"):
+                    data = b"".join(
+                        self._chunk_raw(image_id, name, li, ci, charge, memo)
+                        for ci in range(len(entry["chunks"])))
+                with obs.span("pull.assemble"):
+                    arr = np.frombuffer(data,
+                                        dtype=_resolve_dtype(entry["dtype"]))
+                    leaves.append(arr.reshape(entry["shape"]).copy())
             trees[name] = jax.tree.unflatten(treedef, leaves)
         return trees, pulled
 

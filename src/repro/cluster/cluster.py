@@ -58,30 +58,6 @@ class TimingConstants:
     heartbeat_interval_s: float = 2.0
     heartbeat_timeout_s: float = 6.0
 
-    @classmethod
-    def from_roofline(cls, roofline, **overrides) -> "TimingConstants":
-        """Constants with the data-path throughputs recalibrated from a
-        measured codec roofline (``benchmarks/roofline.py --codec`` ->
-        ``results/codec_roofline.json``, or its loaded dict).
-
-        The class defaults above stay the paper-fitted constants — every
-        regression timeline is pinned to them bit-for-bit — so measured
-        throughput is strictly opt-in via this constructor.
-        """
-        import json
-
-        if isinstance(roofline, str):
-            with open(roofline) as f:
-                roofline = json.load(f)
-        cal = roofline.get("calibration", roofline)
-        kw = {}
-        if cal.get("codec_Bps"):
-            kw["codec_Bps"] = float(cal["codec_Bps"])
-        if cal.get("fingerprint_Bps"):
-            kw["fingerprint_Bps"] = float(cal["fingerprint_Bps"])
-        kw.update(overrides)
-        return cls(**kw)
-
 
 class Node:
     def __init__(self, name: str, sim: Optional[Sim] = None):

@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.models import transformer as T
 from repro.models.config import ModelConfig
 
@@ -86,9 +87,10 @@ class ServingEngine:
         toks = req.prompt or [0]
         self.claimed[slot] = True
         sampled = 0
-        for t, tok in enumerate(toks):
-            next_tok = self._engine_step(forced={slot: (tok, t)})
-            sampled = int(next_tok[slot])
+        with obs.span("engine.admit", request_id=req.request_id):
+            for t, tok in enumerate(toks):
+                next_tok = self._engine_step(forced={slot: (tok, t)})
+                sampled = int(next_tok[slot])
         self.claimed[slot] = False
         self.positions[slot] = len(toks)
         self.active[slot] = True
@@ -108,32 +110,44 @@ class ServingEngine:
         decode their last sampled token; idle lanes re-write position 0 of
         their own lane with token 0 (harmless: they are reset on admit)."""
         forced = forced or {}
-        tokens = np.zeros((self.num_slots, 1), np.int32)
-        positions = np.zeros((self.num_slots, 1), np.int32)
-        for s in range(self.num_slots):
-            if s in forced:
-                tok, pos = forced[s]
-                tokens[s, 0] = tok
-                positions[s, 0] = pos
-            elif self.active[s]:
-                tokens[s, 0] = self.last_token[s]
-                positions[s, 0] = self.positions[s]
-        next_tok, self.cache = self._step_jit(
-            self.cache, jnp.asarray(tokens), jnp.asarray(positions))
-        next_tok = np.asarray(next_tok)
-        for s in range(self.num_slots):
-            if s in forced:
-                continue
-            if not self.active[s]:
-                continue
-            tok = int(next_tok[s])
-            rid = self.request_of_slot[s]
-            self.positions[s] += 1
-            self.generated[rid].append(tok)
-            self.last_token[s] = tok
-            self.budget[s] -= 1
-            if self.budget[s] <= 0 or self.positions[s] >= self.max_seq - 1:
-                self._complete(s)
+        # the span ends before admissions, whose prefill runs steps of its
+        # own: steps never nest
+        with obs.span("engine.step"):
+            tokens = np.zeros((self.num_slots, 1), np.int32)
+            positions = np.zeros((self.num_slots, 1), np.int32)
+            lanes = 0
+            for s in range(self.num_slots):
+                if s in forced:
+                    tok, pos = forced[s]
+                    tokens[s, 0] = tok
+                    positions[s, 0] = pos
+                elif self.active[s]:
+                    tokens[s, 0] = self.last_token[s]
+                    positions[s, 0] = self.positions[s]
+                else:
+                    continue
+                lanes += 1
+            with obs.span("engine.step.dispatch"):
+                next_tok, self.cache = self._step_jit(
+                    self.cache, jnp.asarray(tokens), jnp.asarray(positions))
+            with obs.span("engine.step.wait"):
+                next_tok = np.asarray(next_tok)
+            for s in range(self.num_slots):
+                if s in forced:
+                    continue
+                if not self.active[s]:
+                    continue
+                tok = int(next_tok[s])
+                rid = self.request_of_slot[s]
+                self.positions[s] += 1
+                self.generated[rid].append(tok)
+                self.last_token[s] = tok
+                self.budget[s] -= 1
+                if (self.budget[s] <= 0
+                        or self.positions[s] >= self.max_seq - 1):
+                    self._complete(s)
+            obs.count("engine.steps")
+            obs.count("engine.lanes", lanes)
         self._admit_waiting()
         return next_tok
 
@@ -204,7 +218,11 @@ class ServingEngine:
         }
 
     def load_state(self, tree):
-        self.cache = jax.tree.map(jnp.asarray, tree["cache"])
+        with obs.span("engine.load"):
+            obs.count("restore.h2d_bytes", sum(
+                np.asarray(x).nbytes for x in jax.tree.leaves(tree["cache"])
+                if not isinstance(x, jax.Array)))
+            self.cache = jax.tree.map(jnp.asarray, tree["cache"])
         slots = tree["slots"]
         self.positions = np.asarray(slots["positions"]).copy()
         self.active = np.asarray(slots["active"]).copy()

@@ -324,26 +324,3 @@ def test_migration_identical_across_backends(tmp_path, seed, monkeypatch):
         assert r.verified and r.report.state_verified
         rows[backend] = r.row()
     assert rows["host"] == rows["kernel"]
-
-
-# ---------------------------------------------------------------------------
-# roofline calibration plumbing
-# ---------------------------------------------------------------------------
-
-def test_timing_constants_from_roofline_is_opt_in():
-    """Measured throughput only enters via the constructor; the class
-    defaults (which every regression timeline is pinned to) stay the
-    paper-fitted constants."""
-    from repro.cluster.cluster import TimingConstants
-
-    d = TimingConstants()
-    assert d.codec_Bps == 1.2e9 and d.fingerprint_Bps == 24e9
-    cal = {"calibration": {"codec_Bps": 5e8, "fingerprint_Bps": 1e9}}
-    tc = TimingConstants.from_roofline(cal)
-    assert tc.codec_Bps == 5e8 and tc.fingerprint_Bps == 1e9
-    assert tc.checkpoint_s == d.checkpoint_s
-    assert TimingConstants.from_roofline(cal, codec_Bps=7e8).codec_Bps == 7e8
-    # a bare calibration dict (no wrapper) is accepted too
-    assert TimingConstants.from_roofline(
-        {"codec_Bps": 2e8, "fingerprint_Bps": 0}).fingerprint_Bps == 24e9
-
