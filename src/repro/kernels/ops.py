@@ -10,10 +10,13 @@ interpret mode (slow; used by the kernel-equivalence tests).
 """
 from __future__ import annotations
 
+import functools
 import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax.experimental.layout import Layout
 
 from repro.kernels import ref
 from repro.kernels import flash_attention as _fa
@@ -61,18 +64,53 @@ def attention(q, k, v, *, causal=True, window=0, block_k=1024):
                                    block_k=block_k)
 
 
-def decode_attention(q, k_cache, v_cache, q_pos, k_pos):
-    """Single-token attention over KV cache. q [B,1,H,D]."""
-    if _on_tpu():
-        return _da.decode_attention(q, k_cache, v_cache, q_pos, k_pos)
-    if _interpret_forced():
-        S = k_cache.shape[1]
-        bk = max(min(512, S), 1)
-        while S % bk:
-            bk //= 2
-        return _da.decode_attention(q, k_cache, v_cache, q_pos, k_pos,
-                                    block_k=bk, interpret=True)
+def _device():
+    """The device the kernels compile for."""
+    return jax.devices()[0]
+
+
+@functools.lru_cache(maxsize=None)
+def _seq_minor(shape, dtype, device) -> bool:
+    """Whether ``device`` lays out a ``[..., S, Hkv, D]`` cache with S
+    minor-most and whole heads in its tiles, as a TPU does for heads
+    narrower than its 128 lanes: ``[..., Hkv*D, S]`` is then the same
+    bytes."""
+    layout = Layout.from_pjrt_layout(
+        device.client.get_default_layout(np.dtype(dtype), shape, device))
+    n = len(shape)
+    rows = 1
+    for tile in layout.tiling:
+        rows *= tile[0] if len(tile) > 1 else 1
+    return (tuple(layout.major_to_minor[-3:]) == (n - 2, n - 1, n - 3)
+            and shape[-1] % rows == 0)
+
+
+def decode_attention(q, k_cache, v_cache, q_pos, k_pos, layer=0):
+    """Single-token attention over KV cache. q [B,1,H,D].
+
+    The caches are ``[B,S,Hkv,D]``, or the stacked ``[L,B,S,Hkv,D]`` read at
+    ``layer``.  The kernel reads a stack in place where the device keeps it
+    seq-minor, and otherwise the layer sliced out (a relayout of one layer
+    for its ``[S, Hkv*D]`` view); the jnp path slices the layer.
+    """
+    stacked = k_cache.ndim == 5
+    if _on_tpu() or _interpret_forced():
+        seq_minor = stacked and _seq_minor(k_cache.shape, k_cache.dtype,
+                                           _device())
+        if stacked and not seq_minor:
+            k_cache = _at_layer(k_cache, layer)
+            v_cache, layer = _at_layer(v_cache, layer), 0
+        return _da.decode_attention(
+            q, k_cache, v_cache, q_pos, k_pos, layer, seq_minor=seq_minor,
+            block_k=_fit_block(k_cache.shape[-3], 512),
+            interpret=not _on_tpu())
+    if stacked:
+        k_cache, v_cache = _at_layer(k_cache, layer), _at_layer(v_cache, layer)
     return ref.decode_attention(q, k_cache, v_cache, q_pos=q_pos, k_pos=k_pos)
+
+
+def _at_layer(stack, layer):
+    return jax.lax.dynamic_index_in_dim(stack, layer, keepdims=False)
 
 
 def rglru_scan(x, a_param, gate_a, gate_x, h0=None, *, c: float = 8.0):

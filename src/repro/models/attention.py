@@ -226,8 +226,36 @@ def attn_append(params, x, positions, cfg, cache, *, local: bool):
     return out, new_cache
 
 
-def attn_decode(params, x, positions, cfg, cache, *, local: bool):
-    """One-token decode.  x [B,1,D]; positions [B,1] absolute positions."""
+# lanes whose rows are written by inlined updates; a larger batch writes in
+# a loop of that many lanes per trip, so the program does not grow with it
+WRITE_UNROLL = 16
+
+
+def _write_rows(stacks, rows, layer, slot):
+    """Row ``b`` of each ``rows[name]`` [B, ...] into ``stacks[name]``
+    [L, B, S, ...] at (``layer``, b, ``slot[b]``): one dynamic_update_slice
+    per lane and leaf, so stacks carried through the layer loop are updated
+    in place."""
+    B = slot.shape[0]
+
+    def write(b, stacks):
+        return {name: jax.lax.dynamic_update_slice(
+                    stack,
+                    jax.lax.dynamic_index_in_dim(rows[name], b)[None, None]
+                    .astype(stack.dtype),
+                    (layer, b, slot[b]) + (0,) * (stack.ndim - 3))
+                for name, stack in stacks.items()}
+
+    return jax.lax.fori_loop(0, B, write, stacks,
+                             unroll=min(B, WRITE_UNROLL))
+
+
+def attn_decode(params, x, positions, cfg, cache, layer, *, local: bool):
+    """One-token decode into layer ``layer`` of the stacked cache.
+
+    x [B,1,D]; positions [B,1] absolute positions; cache leaves
+    [L,B,S,...].  Each lane's key, value (and scales) and position are
+    written in place first; attention then reads the layer."""
     B = x.shape[0]
     q, k, v = _project_qkv(params, x, x, cfg)
     if cfg.decode_heads_replicated:
@@ -242,32 +270,27 @@ def attn_decode(params, x, positions, cfg, cache, *, local: bool):
     if cfg.rope_kind != "none":
         q = common.rope_for(cfg, q, positions, local)
         k = common.rope_for(cfg, k, positions, local)
-    S_cache = cache["k"].shape[1]
+    S_cache = cache["k"].shape[2]
     pos_scalar = positions[:, -1] if positions.ndim == 2 else positions[0, :, -1]
     slot = (pos_scalar % S_cache).astype(jnp.int32)  # ring for local layers
-    # Per-row scatter (not one-hot multiply): decode must not rewrite the
-    # whole cache — only attention *reads* it. Keeps the memory roofline
-    # term at O(cache read) instead of 3x.
-    b_idx = jnp.arange(B)
-    new_cache = dict(cache)
-    if "k_scale" in cache:
-        kq, ks = _quantize_kv(k[:, 0])
-        vq, vs = _quantize_kv(v[:, 0])
-        new_cache["k"] = cache["k"].at[b_idx, slot].set(kq)
-        new_cache["v"] = cache["v"].at[b_idx, slot].set(vq)
-        new_cache["k_scale"] = cache["k_scale"].at[b_idx, slot].set(ks)
-        new_cache["v_scale"] = cache["v_scale"].at[b_idx, slot].set(vs)
+    rows = {"pos": pos_scalar.astype(jnp.int32)}
+    quant = "k_scale" in cache
+    if quant:
+        rows["k"], rows["k_scale"] = _quantize_kv(k[:, 0])
+        rows["v"], rows["v_scale"] = _quantize_kv(v[:, 0])
     else:
-        new_cache["k"] = cache["k"].at[b_idx, slot].set(
-            k[:, 0].astype(cache["k"].dtype))
-        new_cache["v"] = cache["v"].at[b_idx, slot].set(
-            v[:, 0].astype(cache["v"].dtype))
-    new_cache["pos"] = cache["pos"].at[b_idx, slot].set(
-        pos_scalar.astype(jnp.int32))
-    k_pos = new_cache["pos"]
+        rows["k"], rows["v"] = k[:, 0], v[:, 0]
+    new_cache = _write_rows({name: cache[name] for name in rows}, rows,
+                            layer, slot)
+    k_pos = common.at_layer(new_cache["pos"], layer)
     if local:
         k_pos = jnp.where(pos_scalar[:, None] - k_pos < cfg.window, k_pos, -1)
-    k_all, v_all = _dequantize_kv(new_cache, x.dtype)
-    out = ops.decode_attention(q, k_all, v_all, pos_scalar, k_pos)
+    if quant:
+        k_all, v_all = _dequantize_kv(common.at_layer(new_cache, layer),
+                                      x.dtype)
+        out = ops.decode_attention(q, k_all, v_all, pos_scalar, k_pos)
+    else:
+        out = ops.decode_attention(q, new_cache["k"], new_cache["v"],
+                                   pos_scalar, k_pos, layer)
     out = out.reshape(B, 1, -1) @ value_of(params["wo"]).astype(x.dtype)
     return out, new_cache

@@ -187,3 +187,10 @@ def unembed(params, x, cfg, table=None):
     """Logits via the (tied) embedding table or a dedicated head."""
     t = value_of(table if table is not None else params["table"])
     return jnp.einsum("bsd,vd->bsv", x, t.astype(x.dtype))
+
+
+def at_layer(stack, layer):
+    """Layer ``layer`` of every leaf of a tree stacked over layers."""
+    return jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, layer, keepdims=False),
+        stack)

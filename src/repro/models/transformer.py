@@ -340,21 +340,31 @@ def cache_logical_axes(cfg: ModelConfig):
     return axes
 
 
-def _apply_block_decode(blk, cache_i, x, positions, cfg, i: int, *, enc_out):
+def _apply_block_decode(blk, stack, layer, x, positions, cfg, i: int, *,
+                        enc_out):
+    """One block of one decode step against layer ``layer`` of the stacked
+    cache ``stack``; returns the new activations and stack.  An attention
+    cache takes one row per lane in place; a recurrent state is rewritten
+    whole every step, so its layer is written back whole."""
     mixer, ffn = cfg.pattern[i]
     h = common.rms_norm(x, blk["norm1"], cfg.norm_eps)
     if mixer in MIXERS_WITH_KV:
         local = mixer == BlockKind.ATTN_LOCAL
-        y, new_cache = attention.attn_decode(blk["attn"], h, positions, cfg,
-                                             cache_i, local=local)
-    elif mixer == BlockKind.RGLRU:
-        y, new_cache = rglru.rglru_decode_step(blk["rglru"], h, cfg, cache_i)
-    elif mixer == BlockKind.MLSTM:
-        y, new_cache = xlstm.mlstm_decode_step(blk["mlstm"], h, cfg, cache_i)
-    elif mixer == BlockKind.SLSTM:
-        y, new_cache = xlstm.slstm_decode_step(blk["slstm"], h, cfg, cache_i)
+        y, stack = attention.attn_decode(blk["attn"], h, positions, cfg,
+                                         stack, layer, local=local)
     else:
-        raise ValueError(mixer)
+        state = common.at_layer(stack, layer)
+        if mixer == BlockKind.RGLRU:
+            y, state = rglru.rglru_decode_step(blk["rglru"], h, cfg, state)
+        elif mixer == BlockKind.MLSTM:
+            y, state = xlstm.mlstm_decode_step(blk["mlstm"], h, cfg, state)
+        elif mixer == BlockKind.SLSTM:
+            y, state = xlstm.slstm_decode_step(blk["slstm"], h, cfg, state)
+        else:
+            raise ValueError(mixer)
+        stack = jax.tree.map(
+            lambda a, s: jax.lax.dynamic_update_index_in_dim(a, s, layer, 0),
+            stack, state)
     x = x + y
     if enc_out is not None and "cross_attn" in blk:
         h = common.rms_norm(x, blk["norm_cross"], cfg.norm_eps)
@@ -368,12 +378,16 @@ def _apply_block_decode(blk, cache_i, x, positions, cfg, i: int, *, enc_out):
         h = common.rms_norm(x, blk["norm2"], cfg.norm_eps)
         y, _ = moe.moe_forward(blk["moe"], h, cfg)
         x = x + y
-    return x, new_cache
+    return x, stack
 
 
 def lm_decode_step(params, tokens, positions, cfg: ModelConfig, cache,
                    unroll: bool = False):
-    """One decode step.  tokens [B,1]; positions [B,1] -> (logits, cache)."""
+    """One decode step.  tokens [B,1]; positions [B,1] -> (logits, cache).
+
+    The stacked cache is the layer loop's carry, not its ``xs``/``ys``:
+    each layer writes its rows into the stack in place, so a step whose
+    cache is donated copies no layer of it."""
     x = common.embed(params["embed"], tokens, cfg)
     if cfg.is_encoder_decoder:
         pe = value_of(params["dec_pos_embed"]).astype(x.dtype)
@@ -382,21 +396,25 @@ def lm_decode_step(params, tokens, positions, cfg: ModelConfig, cache,
     enc_out = cache.get("enc_out") if cfg.is_encoder_decoder else None
     x = constrain(x, ("batch", None, "act_embed"))
 
-    def body(x, xs):
-        group_params, group_cache = xs
-        new_caches = {}
+    def body(carry, group_params, layer):
+        x, stacks = carry
+        stacks = dict(stacks)
         for i in range(len(cfg.pattern)):
-            x, nc = _apply_block_decode(
-                group_params[f"b{i}"], group_cache[f"b{i}"], x, positions,
+            x, stacks[f"b{i}"] = _apply_block_decode(
+                group_params[f"b{i}"], stacks[f"b{i}"], layer, x, positions,
                 cfg, i, enc_out=enc_out)
-            new_caches[f"b{i}"] = nc
-        return x, new_caches
+        return x, stacks
 
-    layer_cache = {k: v for k, v in cache.items() if k.startswith("b")}
-    x, new_layer_cache = _scan_or_unroll(body, x,
-                                         (params["groups"], layer_cache),
-                                         unroll)
-    new_cache = dict(new_layer_cache)
+    carry = (x, {k: v for k, v in cache.items() if k.startswith("b")})
+    if unroll:
+        for g in range(cfg.num_groups):
+            carry = body(carry, jax.tree.map(lambda a: a[g], params["groups"]),
+                         g)
+    else:
+        carry, _ = jax.lax.scan(
+            lambda c, xs: (body(c, *xs), None), carry,
+            (params["groups"], jnp.arange(cfg.num_groups, dtype=jnp.int32)))
+    x, new_cache = carry
     if cfg.is_encoder_decoder:
         new_cache["enc_out"] = cache["enc_out"]
     return _logits(params, x, cfg), new_cache

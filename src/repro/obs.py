@@ -46,6 +46,8 @@ COUNTERS = (
     "engine.steps",          # batched decode steps
     "engine.lanes",          # lanes fed a token, summed over steps
     "restore.h2d_bytes",     # host bytes a restore puts on the device
+    "engine.snapshot_bytes", # cache bytes copied on the device for
+                             # state_tree and for load_state's device leaves
     "push.h2d_bytes",        # host bytes a push hands to the device
     "push.d2h_bytes",        # device bytes a push copies to the host
     "push.parent_bytes",     # parent bytes rebuilt from the store

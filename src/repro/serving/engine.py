@@ -36,14 +36,34 @@ class Completion:
     tokens: List[int]
 
 
-@functools.partial(jax.jit, static_argnames=("cfg",))
+@functools.partial(jax.jit, static_argnames=("cfg",),
+                   donate_argnames=("cache",))
 def _decode_all(params, cfg, cache, tokens, positions):
     logits, cache = T.lm_decode_step(params, tokens, positions, cfg, cache)
     return jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32), cache
 
 
+@functools.cache
+def _owned_step(step):
+    """The engine's jit of ``step`` (``_decode_all``, or a function that
+    calls it): the whole step is one program, which takes the donated
+    cache and hands back the next, so no array outside it ever holds a
+    donated buffer."""
+    return jax.jit(step, static_argnames=("cfg",),
+                   donate_argnames=("cache",))
+
+
+def _nbytes(arrays) -> int:
+    return sum(int(a.nbytes) for a in jax.tree.leaves(arrays))
+
+
 class ServingEngine:
-    """Continuous-batching engine over ``num_slots`` decode lanes."""
+    """Continuous-batching engine over ``num_slots`` decode lanes.
+
+    The engine owns its cache: every step donates it, so the step updates
+    it in place. ``state_tree`` hands out a copy, and ``load_state`` takes
+    a copy of the device arrays it is given; neither shares a buffer with
+    the cache a later step consumes."""
 
     def __init__(self, cfg: ModelConfig, params, num_slots: int = 4,
                  max_seq: int = 512, name: str = "engine"):
@@ -66,7 +86,8 @@ class ServingEngine:
         self.last_msg_id = -1
         self.n_processed = 0
         self.skip_until = -1
-        self._step_jit = functools.partial(_decode_all, self.params, self.cfg)
+        self._step_jit = functools.partial(_owned_step(_decode_all),
+                                           self.params, self.cfg)
 
     # ------------------------------------------------------------------ admin
     def submit(self, req: Request):
@@ -179,14 +200,16 @@ class ServingEngine:
         self.n_processed += 1
 
     def state_tree(self):
-        """Full checkpointable state: KV caches, the slot table, *and* the
-        admitted-request log (per-slot request id + generated-so-far
-        tokens), so a mid-generation checkpoint restores in-flight
-        requests instead of dropping them.  The log is derived from the
-        bookkeeping dicts at snapshot time — no hot-path cost.  A
-        non-empty admission backlog has no array form, so checkpoints are
-        only taken between admissions (the serving wrapper guarantees
-        this by draining ``waiting`` before yielding control)."""
+        """Full checkpointable state: a device copy of the KV caches, the
+        slot table, *and* the admitted-request log (per-slot request id +
+        generated-so-far tokens), so a mid-generation checkpoint restores
+        in-flight requests instead of dropping them.  The copy outlives
+        the steps that follow, which consume the engine's own cache.  The
+        log is derived from the bookkeeping dicts at snapshot time — no
+        hot-path cost.  A non-empty admission backlog has no array form,
+        so checkpoints are only taken between admissions (the serving
+        wrapper guarantees this by draining ``waiting`` before yielding
+        control)."""
         if self.waiting:
             raise RuntimeError(
                 f"{self.name}: state_tree() with {len(self.waiting)} "
@@ -200,8 +223,10 @@ class ServingEngine:
             request[s] = rid
             gen_len[s] = len(toks)
             gen[s, : len(toks)] = toks
+        cache = jax.tree.map(jnp.copy, self.cache)
+        obs.count("engine.snapshot_bytes", _nbytes(cache))
         return {
-            "cache": self.cache,
+            "cache": cache,
             "slots": {
                 "positions": self.positions.copy(),
                 "active": self.active.copy(),
@@ -218,11 +243,19 @@ class ServingEngine:
         }
 
     def load_state(self, tree):
+        """Restore from ``tree``: host leaves are put on the device, device
+        leaves copied, so the caller's tree stays valid after later
+        (donated) steps."""
         with obs.span("engine.load"):
+            leaves = jax.tree.leaves(tree["cache"])
             obs.count("restore.h2d_bytes", sum(
-                np.asarray(x).nbytes for x in jax.tree.leaves(tree["cache"])
+                np.asarray(x).nbytes for x in leaves
                 if not isinstance(x, jax.Array)))
-            self.cache = jax.tree.map(jnp.asarray, tree["cache"])
+            obs.count("engine.snapshot_bytes", _nbytes(
+                [x for x in leaves if isinstance(x, jax.Array)]))
+            self.cache = jax.tree.map(
+                lambda x: jnp.copy(x) if isinstance(x, jax.Array)
+                else jnp.asarray(x), tree["cache"])
         slots = tree["slots"]
         self.positions = np.asarray(slots["positions"]).copy()
         self.active = np.asarray(slots["active"]).copy()

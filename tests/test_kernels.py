@@ -72,6 +72,34 @@ def test_decode_attention_kernel(B, S, H, Hkv, D, dtype):
                                np.asarray(want, np.float32), **tol)
 
 
+@pytest.mark.parametrize("seq_minor", [False, True])
+@pytest.mark.parametrize("block_k", [64, 128, 256])
+@pytest.mark.parametrize("layer", [0, 2, 3])
+def test_decode_attention_reads_a_layer_of_the_stack(layer, block_k,
+                                                     seq_minor):
+    """On the decode step's stacked [L,B,S,Hkv,D] caches the kernel reads
+    layer ``layer`` in place, in either orientation of its K/V blocks: the
+    same as the reference on that slice, and the same bits as the kernel
+    handed the slice itself."""
+    L, B, S, H, Hkv, D = 4, 2, 256, 8, 2, 64
+    ks = jax.random.split(jax.random.PRNGKey(8), 3)
+    q = jax.random.normal(ks[0], (B, 1, H, D), jnp.bfloat16)
+    kc = jax.random.normal(ks[1], (L, B, S, Hkv, D), jnp.bfloat16)
+    vc = jax.random.normal(ks[2], (L, B, S, Hkv, D), jnp.bfloat16)
+    qpos = jnp.array([S // 3, S - 1])
+    kpos = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
+    kpos = jnp.where(kpos <= qpos[:, None], kpos, -1)
+    got = pl_decode(q, kc, vc, qpos, kpos, jnp.int32(layer),
+                    seq_minor=seq_minor, block_k=block_k, interpret=True)
+    want = ref.decode_attention(q, kc[layer], vc[layer], q_pos=qpos,
+                                k_pos=kpos)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), **TOL)
+    sliced = pl_decode(q, kc[layer], vc[layer], qpos, kpos,
+                       block_k=block_k, interpret=True)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(sliced))
+
+
 @pytest.mark.parametrize("B,S,W", [(1, 64, 128), (2, 128, 256), (1, 96, 512)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_rglru_kernel(B, S, W, dtype):

@@ -119,6 +119,89 @@ def test_append_matches_sequential_decode(arch):
                                    rtol=1e-4, atol=1e-4)
 
 
+def _per_layer_fold(params, tokens, positions, cfg, cache):
+    """The decode step as a fold over layers, each layer's cache sliced out
+    of the stack, updated alone and stacked back (the layer loop's xs/ys)."""
+    from repro.models import common
+    x = common.embed(params["embed"], tokens, cfg)
+    layers = []
+    for g in range(cfg.num_groups):
+        group = jax.tree.map(lambda a: a[g], params["groups"])
+        out = {}
+        for i in range(len(cfg.pattern)):
+            one = jax.tree.map(lambda a: a[g:g + 1], cache[f"b{i}"])
+            x, out[f"b{i}"] = T._apply_block_decode(
+                group[f"b{i}"], one, 0, x, positions, cfg, i, enc_out=None)
+        layers.append(out)
+    return (T._logits(params, x, cfg),
+            jax.tree.map(lambda *a: jnp.concatenate(a), *layers))
+
+
+DECODE_FOLD_CASES = {
+    "attention": ("paper_consumer", {}),
+    "local_ring": ("gemma3_4b", {}),          # 5 local (ring) + 1 global
+    "int8_kv": ("paper_consumer", {"kv_cache_dtype": "int8"}),
+    "rglru": ("recurrentgemma_2b", {}),
+    "xlstm": ("xlstm_350m", {}),
+}
+
+
+@pytest.mark.parametrize("unroll", [False, True])
+@pytest.mark.parametrize("case", sorted(DECODE_FOLD_CASES))
+def test_in_place_decode_matches_per_layer_fold(case, unroll):
+    """lm_decode_step writes each lane's row into the stacked cache in
+    place: over several steps, with lanes at different positions and a
+    cache short enough that the ring wraps, its logits and cache equal the
+    per-layer fold's bit for bit."""
+    import dataclasses
+    arch, changes = DECODE_FOLD_CASES[case]
+    cfg = dataclasses.replace(configs.get_smoke(arch), **changes)
+    params = T.init_lm(jax.random.PRNGKey(0), cfg)
+    B, S, steps = 3, 16, 20
+    toks = jax.random.randint(jax.random.PRNGKey(4), (B, steps), 0,
+                              cfg.vocab_size)
+    step = jax.jit(lambda p, t, q, c: T.lm_decode_step(p, t, q, cfg, c,
+                                                       unroll=unroll))
+    fold = jax.jit(lambda p, t, q, c: _per_layer_fold(p, t, q, cfg, c))
+    got = want = T.init_cache(cfg, B, S)
+    for t in range(steps):
+        pos = jnp.asarray([[t], [t + 3], [2 * t]], jnp.int32)
+        lg, got = step(params, toks[:, t:t + 1], pos, got)
+        lw, want = fold(params, toks[:, t:t + 1], pos, want)
+        np.testing.assert_array_equal(np.asarray(lg), np.asarray(lw))
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("seq_minor", [False, True])
+@pytest.mark.parametrize("case", ["attention", "local_ring"])
+def test_in_place_decode_in_the_kernel_matches_per_layer_fold(
+        case, seq_minor, monkeypatch):
+    """The same through the Pallas kernel (interpret mode), which reads the
+    stack in place where the device keeps it seq-minor (a TPU, for heads
+    narrower than 128 lanes) and a sliced layer otherwise."""
+    import dataclasses
+    from repro.kernels import ops
+    monkeypatch.setenv("REPRO_FORCE_PALLAS_INTERPRET", "1")
+    monkeypatch.setattr(ops, "_seq_minor", lambda *a: seq_minor)
+    arch, changes = DECODE_FOLD_CASES[case]
+    cfg = dataclasses.replace(configs.get_smoke(arch), **changes)
+    params = T.init_lm(jax.random.PRNGKey(0), cfg)
+    B, S, steps = 3, 16, 6
+    toks = jax.random.randint(jax.random.PRNGKey(4), (B, steps), 0,
+                              cfg.vocab_size)
+    step = jax.jit(lambda p, t, q, c: T.lm_decode_step(p, t, q, cfg, c))
+    fold = jax.jit(lambda p, t, q, c: _per_layer_fold(p, t, q, cfg, c))
+    got = want = T.init_cache(cfg, B, S)
+    for t in range(steps):
+        pos = jnp.asarray([[t], [t + 3], [2 * t]], jnp.int32)
+        lg, got = step(params, toks[:, t:t + 1], pos, got)
+        lw, want = fold(params, toks[:, t:t + 1], pos, want)
+        np.testing.assert_array_equal(np.asarray(lg), np.asarray(lw))
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 @pytest.mark.parametrize("arch", ARCHS)
 def test_full_config_matches_assignment(arch):
     """The full (published) configs carry the exact assigned hyperparams."""

@@ -14,7 +14,6 @@ from repro import configs, obs
 from repro.broker.broker import Message
 from repro.checkpoint.registry import Registry
 from repro.models import transformer as T
-from repro.serving import engine as engine_mod
 from repro.serving.engine import ServingEngine
 
 CHUNK = 4096
@@ -161,16 +160,16 @@ def test_jit_cache_loads_count_persistent_cache_hits(tmp_path):
     assert c1["jit.compiles"] - c0["jit.compiles"] == 1
 
 
-def test_engine_counts_steps_and_lanes(model, monkeypatch):
+def test_engine_counts_steps_and_lanes(model):
     cfg, params = model
     calls = []
-    step = engine_mod._decode_all
+    eng = ServingEngine(cfg, params, num_slots=2, max_seq=32)
+    step = eng._step_jit
 
     def counted(*args):
         calls.append(1)
         return step(*args)
-    monkeypatch.setattr(engine_mod, "_decode_all", counted)
-    eng = ServingEngine(cfg, params, num_slots=2, max_seq=32)
+    eng._step_jit = counted          # every dispatch of the jitted step
     msgs = messages(range(5))
     c0 = obs.counters()
     for m in msgs:
@@ -182,6 +181,25 @@ def test_engine_counts_steps_and_lanes(model, monkeypatch):
     prompts = sum(len(m.payload["prompt"]) for m in msgs)
     sampled = sum(len(c.tokens) - 1 for c in eng.completions)
     assert c1["engine.lanes"] - c0["engine.lanes"] == prompts + sampled
+
+
+def test_snapshot_bytes_count_the_cache_copies(model):
+    """Each ``state_tree`` copies the cache on the device, and so does each
+    ``load_state`` handed device leaves; a host tree is uploaded instead
+    and copies nothing on the device."""
+    cfg, params = model
+    a = ServingEngine(cfg, params, num_slots=2, max_seq=32)
+    a.process(messages([0])[0])
+    cache_bytes = sum(x.nbytes for x in jax.tree.leaves(a.cache))
+    c0 = obs.counters()
+    trees = [a.state_tree() for _ in range(2)]
+    b = ServingEngine(cfg, params, num_slots=2, max_seq=32)
+    for tree in trees:
+        b.load_state(tree)
+    b.load_state(jax.tree.map(np.asarray, trees[0]))
+    d = diff(c0, obs.counters())
+    assert d["engine.snapshot_bytes"] == cache_bytes * (2 + 2) > 0
+    assert d["restore.h2d_bytes"] == cache_bytes
 
 
 K_SHAPE = (4, 1024)                       # 16384 bytes: 4 chunks

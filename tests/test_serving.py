@@ -75,3 +75,41 @@ def test_engine_is_ms2m_migratable(setup):
     for m in msgs[3:]:
         c.process(m)
     assert c.state_equal(a), "engine replay diverged from full fold"
+
+
+def _messages(ids):
+    return [Message(i, {"request_id": i, "prompt": [2 + i, 4, 6],
+                        "max_new_tokens": 4}, 0.0) for i in ids]
+
+
+def _host(tree):
+    return [np.asarray(x) for x in jax.tree.leaves(tree["cache"])]
+
+
+@pytest.mark.parametrize("source", ["state_tree", "load_state"])
+def test_handed_out_cache_outlives_donated_steps(setup, source):
+    """The engine donates its cache to every step. A ``state_tree``
+    snapshot, and a device tree handed to ``load_state``, share no buffer
+    with it: each still holds what it held after more steps, and a second
+    engine loaded from it replays to the same state as the first."""
+    cfg, params = setup
+    msgs = _messages(range(6))
+    a = ServingEngine(cfg, params, num_slots=2, max_seq=64)
+    for m in msgs[:3]:
+        a.process(m)
+    tree = a.state_tree()
+    before = _host(tree)
+    if source == "load_state":
+        a.load_state(tree)          # the engine steps on from a copy
+    engine_leaves = jax.tree.leaves(a.cache)
+    for m in msgs[3:]:
+        a.process(m)
+    assert all(x.is_deleted() for x in engine_leaves)   # donated
+    assert not any(x.is_deleted() for x in jax.tree.leaves(tree["cache"]))
+    for x, y in zip(before, _host(tree)):
+        np.testing.assert_array_equal(x, y)
+    b = ServingEngine(cfg, params, num_slots=2, max_seq=64)
+    b.load_state(tree)
+    for m in msgs[3:]:
+        b.process(m)
+    assert b.state_equal(a)

@@ -72,6 +72,14 @@ def _decode_case(shape, sds):
         sds((B, S), jnp.int32))
 
 
+def _stacked_decode_case(shape, sds):
+    L, B, S, H, Hkv, D, dtype = shape
+    return da.decode_attention.lower(
+        sds((B, 1, H, D), dtype), sds((L, B, S, Hkv, D), dtype),
+        sds((L, B, S, Hkv, D), dtype), sds((B,), jnp.int32),
+        sds((B, S), jnp.int32), sds((), jnp.int32), seq_minor=True)
+
+
 def _flash_case(shape, sds):
     B, S, H, Hkv, D, dtype = shape
     return fa.flash_attention.lower(
@@ -103,6 +111,10 @@ CASES = {
     # smollm_360m serving: 15 query heads over 5 kv heads of 64, bf16
     "decode_attention-smollm_360m": (
         _decode_case, (8, 128, 15, 5, 64, jnp.bfloat16)),
+    # the engine's step: one layer of smollm_360m's stacked cache, read
+    # in place in the layout the chip keeps it (3 slots over 2048)
+    "decode_attention-smollm_360m_stack": (
+        _stacked_decode_case, (32, 3, 2048, 15, 5, 64, jnp.bfloat16)),
     "flash_attention-smollm_360m_prefill": (
         _flash_case, (8, 32, 15, 5, 64, jnp.bfloat16)),
 }
@@ -117,3 +129,44 @@ def test_kernel_compiles_for_v5e(one_chip, case):
 
     compiled = lower(shape, sds).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_decode_step_copies_no_layer_of_the_cache(topo, one_chip,
+                                                 monkeypatch):
+    """The engine's decode step for smollm_360m at 3 slots over 2048
+    positions, compiled for the chip: its cache is donated and updated in
+    place, and the kernel reads each layer where it lies, so no copy moves
+    a whole layer's keys or values."""
+    import functools
+    import math
+    import re
+
+    from repro import configs
+    from repro.kernels import ops
+    from repro.models import transformer as T
+    from repro.serving import engine
+
+    # the dispatch asks the default backend (the CPU here) for the chip
+    # and its layouts: steer both to the described chip
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    monkeypatch.setattr(ops, "_device", lambda: topo.devices[0])
+    cfg = configs.get_config("smollm_360m")
+    on_chip = lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16
+                                             if a.dtype == jnp.float32
+                                             else a.dtype, sharding=one_chip)
+    params = jax.tree.map(on_chip, jax.eval_shape(
+        functools.partial(T.init_lm, jax.random.PRNGKey(0), cfg)))
+    cache = jax.tree.map(on_chip, jax.eval_shape(
+        functools.partial(T.init_cache, cfg, 3, 2048)))
+    tok = jax.ShapeDtypeStruct((3, 1), jnp.int32, sharding=one_chip)
+    step = engine._owned_step(engine._decode_all)
+    compiled = step.lower(params, cfg, cache, tok, tok).compile()
+    text = compiled.as_text()
+    layer = 3 * 2048 * cfg.num_kv_heads * cfg.resolved_head_dim
+    copies = [[int(d) for d in dims.split(",") if d] for dims in re.findall(
+        r"= [a-z0-9]+\[([\d,]*)\]\S* copy\(", text)]
+    # a copy over the cache's positions as large as one layer's keys
+    of_cache = [c for c in copies if 2048 in c and math.prod(c) >= layer]
+    assert copies and not of_cache, of_cache
+    assert compiled.memory_analysis().alias_size_in_bytes >= sum(
+        a.size * a.dtype.itemsize for a in jax.tree.leaves(cache))
