@@ -243,8 +243,9 @@ class FusedLeafEncoding:
 
     The variable-length RLE pass and blob assembly stay on host: they are
     O(dirty bytes) and data-dependent, the wrong shape for a vector unit.
-    ``raw_seg`` serializes the leaf lazily — only incompressible chunks
-    (raw fallback) ever pay for it.
+    An incompressible chunk (raw fallback) is rebuilt on the host from its
+    XOR words and the parent's bytes (``raw_seg``), so it costs its own
+    bytes and no copy of the leaf; only int8 serializes the leaf, lazily.
     """
 
     def __init__(self, leaf, parent_buf: bytes, codec_name: str,
@@ -257,6 +258,7 @@ class FusedLeafEncoding:
         self._dtype = np.dtype(dtype)
         self._cb = chunk_bytes
         self._nbytes = len(parent_buf)
+        self._parent = parent_buf
         self._raw: Optional[bytes] = None
         self._xor = self._q = self._scale = None
         if codec_name == "xor_rle":
@@ -277,7 +279,15 @@ class FusedLeafEncoding:
         return min(self._cb, self._nbytes - c * self._cb)
 
     def raw_seg(self, c: int) -> bytes:
-        """Raw bytes of chunk ``c`` (lazy leaf serialization, memoized)."""
+        """Raw bytes of chunk ``c``: for ``xor_rle`` its XOR words XOR the
+        parent's bytes (exact, and both are on the host already); for
+        int8 a slice of the leaf, serialized lazily and memoized."""
+        if self._xor is not None:
+            n = self._seg_len(c)
+            xor = self._xor[c].reshape(-1).view(np.uint8)[:n]
+            parent = np.frombuffer(self._parent, np.uint8, count=n,
+                                   offset=c * self._cb)
+            return (xor ^ parent).tobytes()
         if self._raw is None:
             with obs.span("push.serialize"):
                 if not isinstance(self._leaf, np.ndarray):

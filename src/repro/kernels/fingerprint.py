@@ -199,6 +199,19 @@ def as_u32_words(x):
         if pad:
             b = np.concatenate([b, np.zeros(pad, np.uint8)])
         return jnp.asarray(b.view(np.uint32))
+    return _device_words(x)
+
+
+@jax.jit
+def _device_words(x):
+    """``as_u32_words`` of a device array, as one program.
+
+    Elements narrower than a word are packed little-endian, ``group`` to a
+    word, by shifts over strided slices of the flat vector. A bitcast of a
+    ``[n, group]`` view gives the same words, but a TPU pads that view's
+    minor dimension to its 128 lanes: 64x the leaf for 2-byte elements,
+    more than the chip holds for a 300-MB leaf. No intermediate here has
+    a minor dimension narrower than the leaf's own."""
     x = x.reshape(-1)
     if x.dtype == jnp.bool_:
         x = x.astype(jnp.uint8)
@@ -208,12 +221,14 @@ def as_u32_words(x):
     if isz == 8:
         return jax.lax.bitcast_convert_type(x, jnp.uint32).reshape(-1)
     group = 4 // isz  # 2-byte or 1-byte elements: group into one word
+    # integers from here on: a float pad or copy may quiet a NaN's payload
+    x = jax.lax.bitcast_convert_type(x, jnp.uint16 if isz == 2 else jnp.uint8)
     pad = (-x.size) % group
     if pad:
         x = jnp.pad(x, (0, pad))
-    narrow = jnp.uint16 if isz == 2 else jnp.uint8
-    x = jax.lax.bitcast_convert_type(x, narrow).reshape(-1, group)
-    return jax.lax.bitcast_convert_type(x, jnp.uint32)
+    part = lambda j: jax.lax.slice(x, (j,), (x.size,), (group,)).astype(
+        jnp.uint32) << jnp.uint32(8 * isz * j)
+    return functools.reduce(jnp.bitwise_or, map(part, range(group)))
 
 
 def chunked_words(x, chunk_bytes: int):

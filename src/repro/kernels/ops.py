@@ -113,6 +113,22 @@ def _at_layer(stack, layer):
     return jax.lax.dynamic_index_in_dim(stack, layer, keepdims=False)
 
 
+def moe_decode(x, gate_w, gate_ids, live, w_gate, w_up, w_down, layer=0):
+    """Routed experts of a decode step.  x [B,D]; gate_w, gate_ids [B,K];
+    live [B]; expert weights [E,...], or the stacked [L,E,...] read at
+    ``layer`` -> (out [B,D] float32, fetches [B] int32: per lane, the
+    expert weight fetches opened for it, the same on every path)."""
+    from repro.kernels import moe_decode as _md
+
+    if _on_tpu() or _interpret_forced():
+        out = _md.moe_decode(x, gate_w, gate_ids, live, w_gate, w_up, w_down,
+                             layer, interpret=not _on_tpu())
+    else:
+        out = ref.moe_decode(x, gate_w, gate_ids, live, w_gate, w_up, w_down,
+                             layer)
+    return out, _md.fetches(gate_ids, live)
+
+
 def rglru_scan(x, a_param, gate_a, gate_x, h0=None, *, c: float = 8.0):
     """RG-LRU over a sequence. Returns (h_seq, h_last)."""
     if _on_tpu():

@@ -194,6 +194,48 @@ def decode_attention(q, k_cache, v_cache, *, q_pos, k_pos):
 
 
 # ---------------------------------------------------------------------------
+# routed experts at decode
+# ---------------------------------------------------------------------------
+
+def _expert_ffn(x, w_gate, w_up, w_down):
+    """silu(x W_gate) * (x W_up), then W_down; x [..., D] against one
+    expert's (or a leading axis of experts') matrices."""
+    h = jax.nn.silu(jnp.einsum("...d,...df->...f", x, w_gate))
+    h = h * jnp.einsum("...d,...df->...f", x, w_up)
+    return jnp.einsum("...f,...fd->...d", h, w_down)
+
+
+def naive_moe_decode(x, logits, w_gate, w_up, w_down, *, k, live):
+    """Float32 ground truth for one decode token per lane: every expert
+    runs on every lane and the dense top-``k`` gates (softmax over all
+    experts, zero outside the ``k`` largest, renormalised) weight them; no
+    capacity, idle lanes zero. x [B, D]; logits [B, E]; weights [E, ...]."""
+    with jax.default_matmul_precision("highest"):
+        f32 = lambda a: jnp.asarray(a, jnp.float32)
+        probs = jax.nn.softmax(f32(logits), axis=-1)
+        kth = jnp.sort(probs, axis=-1)[:, -k][:, None]
+        gates = jnp.where(probs >= kth, probs, 0.0)
+        gates = gates / gates.sum(-1, keepdims=True) * f32(live)[:, None]
+        per_expert = _expert_ffn(f32(x)[:, None], f32(w_gate)[None],
+                                 f32(w_up)[None], f32(w_down)[None])
+        return jnp.einsum("be,bed->bd", gates, per_expert)
+
+
+def moe_decode(x, gate_w, gate_ids, live, w_gate, w_up, w_down, layer=0):
+    """The routed-expert kernel's jnp formulation: each (lane, k) pair
+    through its own expert's weights, gathered; idle lanes zero. Weights
+    ``[E, ...]``, or stacked ``[L, E, ...]`` read at ``layer``."""
+    if w_gate.ndim == 4:
+        pick = lambda w: jax.lax.dynamic_index_in_dim(w, layer, keepdims=False)
+        w_gate, w_up, w_down = pick(w_gate), pick(w_up), pick(w_down)
+    dt = w_gate.dtype
+    y = _expert_ffn(x.astype(dt)[:, None], w_gate[gate_ids], w_up[gate_ids],
+                    w_down[gate_ids])                            # [B, K, D]
+    gates = gate_w.astype(jnp.float32) * live[:, None]
+    return jnp.einsum("bk,bkd->bd", gates, y.astype(jnp.float32))
+
+
+# ---------------------------------------------------------------------------
 # RG-LRU (griffin / recurrentgemma) oracle
 # ---------------------------------------------------------------------------
 

@@ -237,7 +237,9 @@ def _encode(params, batch, cfg, unroll: bool = False):
 
 
 def _logits(params, x, cfg):
-    x = common.rms_norm(x, params["final_norm"], cfg.norm_eps)
+    # a decode step's float32 residual is rounded once, after the norm
+    x = common.rms_norm(x, params["final_norm"], cfg.norm_eps).astype(
+        cfg.compute_dtype)
     table = (params["unembed"] if "unembed" in params
              else params["embed"]["table"])
     logits = jnp.einsum(
@@ -341,13 +343,25 @@ def cache_logical_axes(cfg: ModelConfig):
 
 
 def _apply_block_decode(blk, stack, layer, x, positions, cfg, i: int, *,
-                        enc_out):
+                        enc_out, experts=None):
     """One block of one decode step against layer ``layer`` of the stacked
-    cache ``stack``; returns the new activations and stack.  An attention
-    cache takes one row per lane in place; a recurrent state is rewritten
-    whole every step, so its layer is written back whole."""
+    cache ``stack``; returns the new activations and stack, and for a block
+    with routed experts the expert weight fetches per lane (else None).
+    An attention cache takes one row per lane in place; a recurrent state
+    is rewritten whole every step, so its layer is written back whole.
+    Routed experts are read from ``experts``, the stack of the block's
+    expert weights, at ``layer``, or else from ``blk``'s own.
+
+    The residual stream ``x`` is carried in float32: each sublayer reads
+    it normed and rounded once to the compute dtype, and its output joins
+    it without a rounding of the sum. Rounded to bfloat16 at every one of
+    a deep model's additions, it drifts far enough from the model to flip
+    routed experts at near ties (see ``moe.moe_decode``)."""
     mixer, ffn = cfg.pattern[i]
-    h = common.rms_norm(x, blk["norm1"], cfg.norm_eps)
+    fetches = None
+    cdt = cfg.compute_dtype
+    x = x.astype(jnp.float32)
+    h = common.rms_norm(x, blk["norm1"], cfg.norm_eps).astype(cdt)
     if mixer in MIXERS_WITH_KV:
         local = mixer == BlockKind.ATTN_LOCAL
         y, stack = attention.attn_decode(blk["attn"], h, positions, cfg,
@@ -367,18 +381,26 @@ def _apply_block_decode(blk, stack, layer, x, positions, cfg, i: int, *,
             stack, state)
     x = x + y
     if enc_out is not None and "cross_attn" in blk:
-        h = common.rms_norm(x, blk["norm_cross"], cfg.norm_eps)
+        h = common.rms_norm(x, blk["norm_cross"], cfg.norm_eps).astype(cdt)
         y = attention.attn_forward(blk["cross_attn"], h, positions, cfg,
                                    causal=False, kv_x=enc_out)
         x = x + y
     if ffn == BlockKind.MLP:
-        h = common.rms_norm(x, blk["norm2"], cfg.norm_eps)
+        h = common.rms_norm(x, blk["norm2"], cfg.norm_eps).astype(cdt)
         x = x + mlp.mlp_forward(blk["mlp"], h, cfg)
     elif ffn == BlockKind.MOE:
+        # in float32: the router reads the normed input unrounded
         h = common.rms_norm(x, blk["norm2"], cfg.norm_eps)
-        y, _ = moe.moe_forward(blk["moe"], h, cfg)
+        y, fetches = moe.moe_decode(blk["moe"], h, cfg,
+                                    _lane_positions(positions) >= 0,
+                                    experts, layer)
         x = x + y
-    return x, stack
+    return x, stack, fetches
+
+
+def _lane_positions(positions):
+    """Each lane's position in a decode step ([B,1], or mrope's [3,B,1])."""
+    return positions[:, -1] if positions.ndim == 2 else positions[0, :, -1]
 
 
 def lm_decode_step(params, tokens, positions, cfg: ModelConfig, cache,
@@ -387,25 +409,42 @@ def lm_decode_step(params, tokens, positions, cfg: ModelConfig, cache,
 
     The stacked cache is the layer loop's carry, not its ``xs``/``ys``:
     each layer writes its rows into the stack in place, so a step whose
-    cache is donated copies no layer of it."""
+    cache is donated copies no layer of it. A lane at a negative position
+    is idle: its row is written as empty, and it is routed to no expert."""
+    logits, cache, _ = lm_decode_step_with_fetches(params, tokens, positions,
+                                                   cfg, cache, unroll)
+    return logits, cache
+
+
+def lm_decode_step_with_fetches(params, tokens, positions, cfg: ModelConfig,
+                                cache, unroll: bool = False):
+    """``lm_decode_step``, and per lane the expert weight fetches its
+    routed experts made, summed over layers: [B] int32, or None for a
+    model without routed experts."""
     x = common.embed(params["embed"], tokens, cfg)
     if cfg.is_encoder_decoder:
         pe = value_of(params["dec_pos_embed"]).astype(x.dtype)
         idx = positions[:, 0] % pe.shape[0]
         x = x + pe[idx][:, None, :]
     enc_out = cache.get("enc_out") if cfg.is_encoder_decoder else None
-    x = constrain(x, ("batch", None, "act_embed"))
+    # the residual stream in float32 (_apply_block_decode)
+    x = constrain(x.astype(jnp.float32), ("batch", None, "act_embed"))
 
     def body(carry, group_params, layer):
-        x, stacks = carry
+        x, stacks, fetched = carry
         stacks = dict(stacks)
         for i in range(len(cfg.pattern)):
-            x, stacks[f"b{i}"] = _apply_block_decode(
+            x, stacks[f"b{i}"], fetches = _apply_block_decode(
                 group_params[f"b{i}"], stacks[f"b{i}"], layer, x, positions,
-                cfg, i, enc_out=enc_out)
-        return x, stacks
+                cfg, i, enc_out=enc_out,
+                experts=params["groups"][f"b{i}"].get("moe"))
+            if fetches is not None:
+                fetched = fetched + fetches
+        return x, stacks, fetched
 
-    carry = (x, {k: v for k, v in cache.items() if k.startswith("b")})
+    routed = any(ffn == BlockKind.MOE for _, ffn in cfg.pattern)
+    carry = (x, {k: v for k, v in cache.items() if k.startswith("b")},
+             jnp.zeros(x.shape[0], jnp.int32) if routed else None)
     if unroll:
         for g in range(cfg.num_groups):
             carry = body(carry, jax.tree.map(lambda a: a[g], params["groups"]),
@@ -414,10 +453,10 @@ def lm_decode_step(params, tokens, positions, cfg: ModelConfig, cache,
         carry, _ = jax.lax.scan(
             lambda c, xs: (body(c, *xs), None), carry,
             (params["groups"], jnp.arange(cfg.num_groups, dtype=jnp.int32)))
-    x, new_cache = carry
+    x, new_cache, fetched = carry
     if cfg.is_encoder_decoder:
         new_cache["enc_out"] = cache["enc_out"]
-    return _logits(params, x, cfg), new_cache
+    return _logits(params, x, cfg), new_cache, fetched
 
 
 def lm_append(params, tokens, positions, cfg: ModelConfig, cache):

@@ -45,6 +45,8 @@ SPANS = (
 COUNTERS = (
     "engine.steps",          # batched decode steps
     "engine.lanes",          # lanes fed a token, summed over steps
+    "moe.expert_loads",      # (layer, expert) weight fetches of the decode
+                             # step's routed experts, summed over steps
     "restore.h2d_bytes",     # host bytes a restore puts on the device
     "engine.snapshot_bytes", # cache bytes copied on the device for
                              # state_tree and for load_state's device leaves

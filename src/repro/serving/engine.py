@@ -39,8 +39,16 @@ class Completion:
 @functools.partial(jax.jit, static_argnames=("cfg",),
                    donate_argnames=("cache",))
 def _decode_all(params, cfg, cache, tokens, positions):
-    logits, cache = T.lm_decode_step(params, tokens, positions, cfg, cache)
-    return jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32), cache
+    """One step of every lane -> (next tokens [B], cache). For a model
+    with routed experts the tokens come as row 0 of a [2, B] array whose
+    row 1 holds the expert weight fetches each lane's experts made, so
+    that both reach the host in the one copy of the step's output."""
+    logits, cache, fetches = T.lm_decode_step_with_fetches(
+        params, tokens, positions, cfg, cache)
+    tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+    if fetches is not None:
+        tok = jnp.stack([tok, fetches])
+    return tok, cache
 
 
 @functools.cache
@@ -128,14 +136,15 @@ class ServingEngine:
 
         ``forced`` maps slot -> (token, position): lanes being prefilled
         consume their prompt token at its position; other active lanes
-        decode their last sampled token; idle lanes re-write position 0 of
-        their own lane with token 0 (harmless: they are reset on admit)."""
+        decode their last sampled token; idle lanes take position -1, which
+        writes their own lane's row as empty and routes them to no expert
+        (harmless: a lane's rows are rewritten from position 0 on admit)."""
         forced = forced or {}
         # the span ends before admissions, whose prefill runs steps of its
         # own: steps never nest
         with obs.span("engine.step"):
             tokens = np.zeros((self.num_slots, 1), np.int32)
-            positions = np.zeros((self.num_slots, 1), np.int32)
+            positions = np.full((self.num_slots, 1), -1, np.int32)
             lanes = 0
             for s in range(self.num_slots):
                 if s in forced:
@@ -153,6 +162,9 @@ class ServingEngine:
                     self.cache, jnp.asarray(tokens), jnp.asarray(positions))
             with obs.span("engine.step.wait"):
                 next_tok = np.asarray(next_tok)
+            if next_tok.ndim == 2:
+                next_tok, fetches = next_tok
+                obs.count("moe.expert_loads", int(fetches.sum()))
             for s in range(self.num_slots):
                 if s in forced:
                     continue

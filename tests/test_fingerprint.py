@@ -101,3 +101,22 @@ def test_bfloat16_words():
     assert out.shape == (1, fp.FP_WORDS)
     y = jnp.concatenate([x[:100] + 1, x[100:]])
     assert (np.asarray(ops.chunk_fingerprint(y, CB)) != out).any()
+
+
+@pytest.mark.parametrize("n", [1, 2, 7, 4096, 100_003])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.uint16, jnp.float16,
+                                   jnp.int8, jnp.bool_])
+def test_device_words_equal_the_host_byte_view(dtype, n):
+    """A device leaf's words are its bytes read as little-endian uint32,
+    the tail zero-padded: the words a host leaf gives, so chunk
+    fingerprints and image ids do not depend on where the leaf lives."""
+    rng = np.random.default_rng(n)
+    bits = rng.integers(0, 1 << 16, n, dtype=np.uint32).astype(np.uint16)
+    host = (bits.view(jnp.dtype(dtype)) if jnp.dtype(dtype).itemsize == 2
+            else (bits % 2 if dtype == jnp.bool_ else bits % 256).astype(
+                jnp.dtype(dtype)))
+    got = np.asarray(fp.as_u32_words(jnp.asarray(host)))
+    np.testing.assert_array_equal(got, np.asarray(fp.as_u32_words(host)))
+    b = host.view(np.uint8)
+    b = np.concatenate([b, np.zeros(-b.size % 4, np.uint8)])
+    np.testing.assert_array_equal(got, b.view(np.uint32))

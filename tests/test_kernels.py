@@ -8,6 +8,7 @@ import pytest
 from repro.kernels import ref
 from repro.kernels.decode_attention import decode_attention as pl_decode
 from repro.kernels.flash_attention import flash_attention as pl_flash
+from repro.kernels import moe_decode as md
 from repro.kernels.rglru import rglru as pl_rglru
 
 TOL = dict(rtol=2e-2, atol=2e-3)  # bf16-friendly
@@ -97,6 +98,85 @@ def test_decode_attention_reads_a_layer_of_the_stack(layer, block_k,
                                np.asarray(want, np.float32), **TOL)
     sliced = pl_decode(q, kc[layer], vc[layer], qpos, kpos,
                        block_k=block_k, interpret=True)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(sliced))
+
+
+def _moe_case(case):
+    """x [B,D], router logits [B,E], live [B], expert weights and k for a
+    routing case; the weights are seeded normal draws over sqrt(fan_in)."""
+    E, K, B, D, F = 32, 8, 3, 128, 64
+    live = np.ones(B, bool)
+    ks = jax.random.split(jax.random.PRNGKey(11), 5)
+    logits = jax.random.normal(ks[0], (B, E))
+    if case == "shared":        # every lane routes to the same 8 experts
+        logits = jnp.broadcast_to(logits[:1], (B, E))
+    elif case == "one_expert":  # top 1, every lane on expert 5
+        K = 1
+        logits = logits.at[:, 5].add(10.0)
+    elif case == "idle":        # lanes 1 and 3 of 4 are empty slots
+        B = 4
+        logits = jax.random.normal(ks[0], (B, E))
+        live = np.array([True, False, True, False])
+    x = jax.random.normal(ks[1], (B, D))
+    w = [jax.random.normal(k, s) / np.sqrt(s[1]) for k, s in
+         zip(ks[2:], [(E, D, F), (E, D, F), (E, F, D)])]
+    return x, logits, jnp.asarray(live), w, K
+
+
+MOE_CASES = ["random", "shared", "one_expert", "idle"]
+
+
+@pytest.mark.parametrize("case", MOE_CASES)
+def test_moe_decode_kernel(case):
+    """The routed-expert kernel (interpret mode) and its jnp formulation
+    against dense float32 top-k gating over every expert. Float32
+    throughout, so both agree to float32 rounding of sums over D and F
+    (TOL32); an idle lane's output is exactly zero."""
+    from repro.models.moe import top_k_gates
+    x, logits, live, w, K = _moe_case(case)
+    _, gate_w, gate_ids = top_k_gates(logits, K)
+    want = ref.naive_moe_decode(x, logits, *w, k=K, live=live)
+    got = md.moe_decode(x, gate_w, gate_ids, live, *w, interpret=True)
+    lowered = ref.moe_decode(x, gate_w, gate_ids, live, *w)
+    for out in (got, lowered):
+        np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                                   **TOL32)
+    assert not np.asarray(got)[~np.asarray(live)].any()
+
+
+@pytest.mark.parametrize("case", MOE_CASES)
+def test_moe_decode_fetches_each_routed_expert_once(case):
+    """In the kernel's pair order the expert (the weights' block index)
+    changes only where a new routed expert starts: each expert a live lane
+    routes to is fetched once, no other is, and idle lanes' pairs fetch
+    nothing. ``fetches`` charges each fetch to one live lane."""
+    from repro.models.moe import top_k_gates
+    x, logits, live, w, K = _moe_case(case)
+    _, _, gate_ids = top_k_gates(logits, K)
+    _, expert, lane, on = (np.asarray(a) for a in md.route(gate_ids, live))
+    routed = set(np.asarray(gate_ids)[np.asarray(live)].ravel().tolist())
+    opened = [int(e) for i, e in enumerate(expert)
+              if i == 0 or e != expert[i - 1]]
+    assert sorted(opened) == sorted(routed)
+    assert set(expert[on == 1].tolist()) == routed
+    assert not on[lane == 1].any() if case == "idle" else on.all()
+    per_lane = np.asarray(md.fetches(gate_ids, live))
+    assert per_lane.sum() == len(routed)
+    assert not per_lane[~np.asarray(live)].any()
+
+
+@pytest.mark.parametrize("layer", [0, 2])
+def test_moe_decode_reads_a_layer_of_the_stack(layer):
+    """On the decode step's stacked [L,E,...] expert weights the kernel
+    reads layer ``layer`` in place: the same bits as handed the slice."""
+    from repro.models.moe import top_k_gates
+    x, logits, live, w, K = _moe_case("random")
+    stack = [jnp.stack([a * (1 + i) for i in range(3)]) for a in w]
+    _, gate_w, gate_ids = top_k_gates(logits, K)
+    got = md.moe_decode(x, gate_w, gate_ids, live, *stack, jnp.int32(layer),
+                        interpret=True)
+    sliced = md.moe_decode(x, gate_w, gate_ids, live,
+                           *[a[layer] for a in stack], interpret=True)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(sliced))
 
 

@@ -130,7 +130,7 @@ def _per_layer_fold(params, tokens, positions, cfg, cache):
         out = {}
         for i in range(len(cfg.pattern)):
             one = jax.tree.map(lambda a: a[g:g + 1], cache[f"b{i}"])
-            x, out[f"b{i}"] = T._apply_block_decode(
+            x, out[f"b{i}"], _ = T._apply_block_decode(
                 group[f"b{i}"], one, 0, x, positions, cfg, i, enc_out=None)
         layers.append(out)
     return (T._logits(params, x, cfg),
@@ -285,3 +285,135 @@ def test_moe_routing_mass_conservation():
     assert out.shape == x.shape
     assert np.isfinite(float(aux))
     assert not bool(jnp.isnan(out).any())
+
+
+def test_routed_decode_matches_forward_and_capacity_dispatch(monkeypatch):
+    """granite's smoke config through the cache, token by token: the decode
+    step with the routed-expert kernel (interpret mode) gives lm_forward's
+    logits at every position, and the capacity dispatch's run at S = 1 in
+    its place; lane 2 stays idle (position -1) for the first steps, which
+    changes no live lane. Float32 at smoke widths: 1e-4 leaves room for
+    sums taken in other orders (the forward's blockwise attention, the
+    kernel's per-pair expert sums), while one expert left out or one gate
+    not renormalised moves logits by 1e-2 or more."""
+    import dataclasses
+    from repro.models import moe as moelib
+    monkeypatch.setenv("REPRO_FORCE_PALLAS_INTERPRET", "1")
+    # dropless capacity, so that the forward drops no token either
+    cfg = dataclasses.replace(configs.get_smoke("granite_moe_1b_a400m"),
+                              capacity_factor=8.0)
+    params = T.init_lm(jax.random.PRNGKey(0), cfg)
+    B, S, late = 3, 10, 3
+    toks = jax.random.randint(jax.random.PRNGKey(5), (B, S), 0,
+                              cfg.vocab_size)
+    fwd, _ = T.lm_forward(params, {"tokens": toks, "labels": toks}, cfg)
+
+    def decode():
+        step = jax.jit(lambda p, t, q, c: T.lm_decode_step(p, t, q, cfg, c))
+        cache, out = T.init_cache(cfg, B, 16), []
+        for t in range(S):
+            pos = np.array([t, t, t - late])
+            tok = np.asarray(toks)[np.arange(B), np.maximum(pos, 0)]
+            pos = np.where(pos >= 0, pos, -1)
+            lg, cache = step(params, jnp.asarray(tok[:, None], jnp.int32),
+                             jnp.asarray(pos[:, None], jnp.int32), cache)
+            out.append(np.asarray(lg[:, 0]))
+        return np.stack(out, 1)    # [B, S, V]; lane 2 late by ``late``
+
+    def capacity(params, x, cfg, live, experts=None, layer=0):
+        out, _ = moelib._moe_forward_local(params, x, cfg)
+        return out, jnp.zeros(x.shape[0], jnp.int32)
+
+    routed = decode()
+    monkeypatch.setattr(moelib, "moe_decode", capacity)
+    dispatched = decode()
+    fwd = np.asarray(fwd)
+    for got in (routed, dispatched):
+        np.testing.assert_allclose(got[:2], fwd[:2], rtol=1e-4, atol=1e-4)
+        np.testing.assert_allclose(got[2, late:], fwd[2, :S - late],
+                                   rtol=1e-4, atol=1e-4)
+
+
+def test_decode_router_breaks_near_ties_in_float32():
+    """The decode step's router reads the normed input unrounded, in
+    float32: two experts whose logits differ by less than bfloat16 can tell
+    apart (1.00293 and 1.00195 both round to 1.0) are ordered as float32
+    orders them. Only the first four inputs reach the router: expert 2
+    always wins the first of the two slots, and expert 1 beats expert 0
+    for the second by 0.001. The same input rounded to
+    bfloat16 first, as the capacity dispatch reads it, ties them and takes
+    expert 0, so the case tells the two apart; the experts' outputs then
+    differ by far more than the 2e-2 that bfloat16 expert products leave."""
+    import dataclasses
+    from repro.kernels import ref
+    from repro.models import moe as moelib
+    cfg = dataclasses.replace(configs.get_smoke("granite_moe_1b_a400m"),
+                              dtype="bfloat16")
+    D, E, F, K = cfg.d_model, cfg.num_experts, cfg.d_ff, cfg.num_experts_per_tok
+    assert (E, K) == (4, 2)
+    ks = jax.random.split(jax.random.PRNGKey(3), 3)
+    w = {name: (jax.random.normal(k, (E,) + shape) / np.sqrt(shape[0])
+                ).astype(jnp.bfloat16)
+         for k, (name, shape) in zip(ks, (("w_gate", (D, F)),
+                                          ("w_up", (D, F)),
+                                          ("w_down", (F, D))))}
+    router = np.zeros((D, E), np.float32)
+    router[0, 0] = router[1, 1] = 1.0
+    router[2, 2] = 2.0
+    router[3, 3] = -2.0
+    x = np.random.default_rng(0).normal(size=(1, 1, D)).astype(np.float32)
+    x[0, 0, :4] = [1 + 2 ** -9, 1 + 3 * 2 ** -10, 1.0, 1.0]
+    params = dict(w, router=jnp.asarray(router, jnp.bfloat16))
+    live = jnp.ones((1,), bool)
+
+    out, _ = moelib.moe_decode(params, jnp.asarray(x), cfg, live)
+    logits = x[:, 0] @ router
+    want = ref.naive_moe_decode(x[:, 0], logits, w["w_gate"], w["w_up"],
+                                w["w_down"], k=K, live=live)
+    _, _, rounded_ids = moelib.top_k_gates(
+        jnp.asarray(x[:, 0], jnp.bfloat16).astype(jnp.float32) @ router, K)
+    assert sorted(np.asarray(rounded_ids)[0].tolist()) == [0, 2]
+    np.testing.assert_allclose(np.asarray(out[:, 0], np.float32),
+                               np.asarray(want), rtol=2e-2, atol=2e-2)
+    wrong = ref.naive_moe_decode(
+        x[:, 0], np.where(np.arange(E) == 1, -9.0, logits), w["w_gate"],
+        w["w_up"], w["w_down"], k=K, live=live)
+    assert np.abs(np.asarray(want) - np.asarray(wrong)).max() > 0.2
+
+
+def test_decode_step_carries_the_residual_in_float32():
+    """In a bfloat16 model the decode step's residual stream is float32:
+    a block takes the embedding's bfloat16 rows and returns float32, the
+    routed experts' sum joins it unrounded, and the step's logits (about
+    0.5 at most) stay within 0.02 of the same step run wholly in float32 on
+    the same bfloat16-valued weights: each sublayer's input is still
+    rounded to bfloat16 once (relative error 2**-9), which over the smoke
+    config's two layers moves them by about 0.005."""
+    import dataclasses
+    from repro.models import common
+    from repro.models import moe as moelib
+    cfg = dataclasses.replace(configs.get_smoke("granite_moe_1b_a400m"),
+                              dtype="bfloat16")
+    params = jax.tree.map(lambda a: a.astype(jnp.bfloat16),
+                          T.init_lm(jax.random.PRNGKey(0), cfg))
+    B = 2
+    toks = jnp.asarray([[3], [7]], jnp.int32)
+    pos = jnp.asarray([[0], [0]], jnp.int32)
+    x = common.embed(params["embed"], toks, cfg)
+    assert x.dtype == jnp.bfloat16
+    group = jax.tree.map(lambda a: a[0], params["groups"])
+    cache = T.init_cache(cfg, B, 8)
+    y, _, _ = T._apply_block_decode(group["b0"], cache["b0"], 0, x, pos, cfg,
+                                    0, enc_out=None)
+    assert y.dtype == jnp.float32
+    h = common.rms_norm(y, group["b0"]["norm2"], cfg.norm_eps)
+    out, _ = moelib.moe_decode(group["b0"]["moe"], h, cfg,
+                               jnp.ones((B,), bool))
+    assert out.dtype == jnp.float32
+
+    lg, _ = T.lm_decode_step(params, toks, pos, cfg, cache)
+    f32 = dataclasses.replace(cfg, dtype="float32")
+    want, _ = T.lm_decode_step(
+        jax.tree.map(lambda a: a.astype(jnp.float32), params), toks, pos,
+        f32, T.init_cache(f32, B, 8))
+    np.testing.assert_allclose(np.asarray(lg), np.asarray(want), atol=0.02)

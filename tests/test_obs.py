@@ -176,11 +176,47 @@ def test_engine_counts_steps_and_lanes(model):
         eng.process(m)
     c1 = obs.counters()
     assert c1["engine.steps"] - c0["engine.steps"] == len(calls) > 0
+    assert c1["moe.expert_loads"] == c0["moe.expert_loads"]
     # a lane is fed once per prompt token and once per token sampled after
     # the first (the last prompt token's pass samples the first)
     prompts = sum(len(m.payload["prompt"]) for m in msgs)
     sampled = sum(len(c.tokens) - 1 for c in eng.completions)
     assert c1["engine.lanes"] - c0["engine.lanes"] == prompts + sampled
+
+
+def test_expert_loads_count_the_routed_experts(monkeypatch):
+    """``moe.expert_loads`` adds, for each engine step, the distinct
+    (layer, expert) pairs that the step's live lanes route to: counted here
+    from the router's expert ids, recorded outside the program as each
+    layer runs. A layer never fetches more than min(E, K x live lanes)."""
+    import dataclasses
+    from repro.kernels import ops
+    cfg = dataclasses.replace(configs.get_smoke("granite_moe_1b_a400m"),
+                              name="granite-moe-obs")
+    params = T.init_lm(jax.random.PRNGKey(0), cfg)
+    layers = []          # per layer run: (distinct routed experts, lanes)
+    moe_decode = ops.moe_decode
+
+    def record(ids, on):
+        ids, on = np.asarray(ids), np.asarray(on)
+        layers.append((len(set(ids[on].ravel().tolist())), int(on.sum())))
+
+    def recorded(x, gate_w, gate_ids, live, *weights):
+        jax.debug.callback(record, gate_ids, live)
+        return moe_decode(x, gate_w, gate_ids, live, *weights)
+    monkeypatch.setattr(ops, "moe_decode", recorded)
+    eng = ServingEngine(cfg, params, num_slots=3, max_seq=32)
+    c0 = obs.counters()
+    for m in messages(range(5)):
+        eng.process(m)
+    c1 = obs.counters()
+    jax.effects_barrier()
+    steps = c1["engine.steps"] - c0["engine.steps"]
+    assert len(layers) == steps * cfg.num_layers > 0
+    assert c1["moe.expert_loads"] - c0["moe.expert_loads"] == sum(
+        n for n, _ in layers)
+    K, E = cfg.num_experts_per_tok, cfg.num_experts
+    assert all(0 < n <= min(E, K * lanes) for n, lanes in layers)
 
 
 def test_snapshot_bytes_count_the_cache_copies(model):
@@ -272,7 +308,7 @@ def test_byte_counters_match_the_leaf_shapes(case, model, tmp_path):
     # the parent of every leaf, and the host leaves themselves
     assert d["push.h2d_bytes"] == K + POS + S + POS + S
     xor = sum(xor_plane_bytes(n) for n in (K, POS, S))
-    # a chunk that encodes no smaller than it is goes raw: the device
-    # leaf is then serialized whole
-    raw = K if case == "delta_dense" else 0
-    assert d["push.d2h_bytes"] == xor + FPS + raw
+    # a chunk that encodes no smaller than it is goes raw: its bytes are
+    # rebuilt on the host from the XOR plane and the parent, so no case
+    # copies a device leaf beyond the XOR plane and the fingerprints
+    assert d["push.d2h_bytes"] == xor + FPS

@@ -23,6 +23,7 @@ from repro.kernels import codec as ck
 from repro.kernels import decode_attention as da
 from repro.kernels import fingerprint as fp
 from repro.kernels import flash_attention as fa
+from repro.kernels import moe_decode as md
 
 
 @pytest.fixture(scope="module")
@@ -80,6 +81,15 @@ def _stacked_decode_case(shape, sds):
         sds((B, S), jnp.int32), sds((), jnp.int32), seq_minor=True)
 
 
+def _moe_decode_case(shape, sds):
+    L, E, B, K, D, F, dtype = shape
+    return md.moe_decode.lower(
+        sds((B, D), dtype), sds((B, K), jnp.float32), sds((B, K), jnp.int32),
+        sds((B,), jnp.bool_), sds((L, E, D, F), dtype),
+        sds((L, E, D, F), dtype), sds((L, E, F, D), dtype),
+        sds((), jnp.int32))
+
+
 def _flash_case(shape, sds):
     B, S, H, Hkv, D, dtype = shape
     return fa.flash_attention.lower(
@@ -115,6 +125,10 @@ CASES = {
     # in place in the layout the chip keeps it (3 slots over 2048)
     "decode_attention-smollm_360m_stack": (
         _stacked_decode_case, (32, 3, 2048, 15, 5, 64, jnp.bfloat16)),
+    # the engine's step: granite_moe_1b_a400m's routed experts, one layer
+    # of the stacked [24, 32, ...] expert weights read in place, 3 lanes
+    "moe_decode-granite_moe_1b_a400m_stack": (
+        _moe_decode_case, (24, 32, 3, 8, 1024, 512, jnp.bfloat16)),
     "flash_attention-smollm_360m_prefill": (
         _flash_case, (8, 32, 15, 5, 64, jnp.bfloat16)),
 }
@@ -168,5 +182,70 @@ def test_decode_step_copies_no_layer_of_the_cache(topo, one_chip,
     # a copy over the cache's positions as large as one layer's keys
     of_cache = [c for c in copies if 2048 in c and math.prod(c) >= layer]
     assert copies and not of_cache, of_cache
+    assert compiled.memory_analysis().alias_size_in_bytes >= sum(
+        a.size * a.dtype.itemsize for a in jax.tree.leaves(cache))
+
+
+def test_fingerprint_and_xor_of_a_300_mb_leaf_fit(one_chip):
+    """One granite_moe_1b_a400m KV leaf, bf16[24,3,4096,8,64] (302 MB), on
+    the registry's 384 KiB grid: its words, fingerprints and XOR against a
+    parent compile for the chip in at most 3x the leaf of temporaries. A
+    bitcast of a [n, 2] uint16 view, whose minor dimension the chip pads to
+    128 lanes, asked for 64x (19.3 GB)."""
+    import math
+
+    shape, chunk = (24, 3, 4096, 8, 64), 384 * 1024
+    leaf = 2 * math.prod(shape)
+    words = (leaf // chunk, chunk // (4 * fp.LANES), fp.LANES)
+
+    def push(x, parent_words):
+        lanes, xor = ck.xor_fp_lanes(fp.chunked_words(x, chunk),
+                                     parent_words)
+        return fp.collapse_lanes(lanes), xor
+
+    compiled = jax.jit(push).lower(
+        jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip),
+        jax.ShapeDtypeStruct(words, jnp.uint32, sharding=one_chip)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes <= 3 * leaf
+
+
+def test_moe_decode_step_copies_no_expert(topo, one_chip, monkeypatch):
+    """The engine's decode step for granite_moe_1b_a400m at 3 slots over
+    4096 positions, compiled for the chip: the routed-expert kernel reads
+    each layer's experts from the stacked weights where they lie, so no
+    layer's experts are sliced out or copied, and the cache is updated in
+    place."""
+    import functools
+    import re
+
+    from repro import configs
+    from repro.kernels import ops
+    from repro.models import transformer as T
+    from repro.serving import engine
+
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    monkeypatch.setattr(ops, "_device", lambda: topo.devices[0])
+    cfg = configs.get_config("granite_moe_1b_a400m")
+    on_chip = lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16
+                                             if a.dtype == jnp.float32
+                                             else a.dtype, sharding=one_chip)
+    params = jax.tree.map(on_chip, jax.eval_shape(
+        functools.partial(T.init_lm, jax.random.PRNGKey(0), cfg)))
+    cache = jax.tree.map(on_chip, jax.eval_shape(
+        functools.partial(T.init_cache, cfg, 3, 4096)))
+    tok = jax.ShapeDtypeStruct((3, 1), jnp.int32, sharding=one_chip)
+    step = engine._owned_step(engine._decode_all)
+    compiled = step.lower(params, cfg, cache, tok, tok).compile()
+    text = compiled.as_text()
+    L, E, d, ff = cfg.num_layers, cfg.num_experts, cfg.d_model, cfg.d_ff
+    kernel = [ln for ln in text.splitlines()
+              if ln.strip().startswith("%moe_decode")]
+    # its operands are the stacks themselves, not a layer sliced out
+    stack = f"bf16[{L},{E},{d},{ff}]", f"bf16[{L},{E},{ff},{d}]"
+    assert kernel and all(ln.count(stack[0]) == 2 and stack[1] in ln
+                          for ln in kernel), kernel
+    # and no array in the program holds one layer's experts
+    layer = re.compile(rf"\[(1,)?{E},({d},{ff}|{ff},{d})\]")
+    assert not layer.search(text)
     assert compiled.memory_analysis().alias_size_in_bytes >= sum(
         a.size * a.dtype.itemsize for a in jax.tree.leaves(cache))
