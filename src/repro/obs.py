@@ -29,7 +29,7 @@ from jax.profiler import TraceAnnotation
 SPANS = (
     "engine.step",           # one batched decode step, before admissions
     "engine.step.dispatch",  # the jitted step, until the call returns
-    "engine.step.wait",      # the sampled tokens copied to the host
+    "engine.sync",           # queued steps' sampled tokens read on the host
     "engine.admit",          # one request's prompt folded into its slot
     "engine.load",           # a restored state put on the device
     "push.parent",           # the parent's chunks rebuilt from the store
@@ -45,6 +45,7 @@ SPANS = (
 COUNTERS = (
     "engine.steps",          # batched decode steps
     "engine.lanes",          # lanes fed a token, summed over steps
+    "engine.syncs",          # syncs that read queued steps' tokens
     "moe.expert_loads",      # (layer, expert) weight fetches of the decode
                              # step's routed experts, summed over steps
     "restore.h2d_bytes",     # host bytes a restore puts on the device

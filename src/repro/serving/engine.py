@@ -1,12 +1,16 @@
 """Slot-based serving engine with continuous batching.
 
 A fixed decode batch of ``num_slots`` sequences; requests admit into free
-slots (chunked prefill via ``lm_append``), every engine step decodes one
-token for all active slots, finished sequences free their slot.  State =
-(slot KV caches, slot table) — one pytree, which makes the *whole engine*
-an MS2M-migratable worker: its message log is the admitted request stream,
-and replaying it from a checkpoint reproduces the engine bit-exactly
-(tests/test_serving.py).
+slots, their prompt folded one token per engine step (forced lanes of the
+batched step, while the other lanes keep decoding), every engine step
+decodes one token for all active slots, finished sequences free their
+slot.  Steps are dispatched back to back: each lane's last sampled token
+stays on the device and feeds the next step, and the sampled tokens reach
+the host at a sync, at the end of every message and before the state is
+read or replaced.  State = (slot KV caches, slot table) — one pytree,
+which makes the *whole engine* an MS2M-migratable worker: its message log
+is the admitted request stream, and replaying it from a checkpoint
+reproduces the engine bit-exactly (tests/test_serving.py).
 """
 from __future__ import annotations
 
@@ -54,10 +58,20 @@ def _decode_all(params, cfg, cache, tokens, positions):
 @functools.cache
 def _owned_step(step):
     """The engine's jit of ``step`` (``_decode_all``, or a function that
-    calls it): the whole step is one program, which takes the donated
-    cache and hands back the next, so no array outside it ever holds a
-    donated buffer."""
-    return jax.jit(step, static_argnames=("cfg",),
+    calls it), fed on the device: ``host`` is the step's one host input,
+    int32 ``[3, B]`` of forced tokens, positions and a mask of the lanes
+    fed their own last sampled token, which ``last`` ``[B]`` holds on the
+    device. Returns ``step``'s output, the lanes' sampled tokens (the next
+    ``last``) and the cache. The whole step is one program, named after
+    ``_decode_all``, which takes the donated cache and hands back the
+    next, so no array outside it ever holds a donated buffer."""
+    def _decode_all_fed(params, cfg, cache, host, last):
+        tokens = jnp.where(host[2] != 0, last, host[0])
+        out, cache = step(params, cfg, cache, tokens[:, None],
+                          host[1][:, None])
+        return out, out if out.ndim == 1 else out[0], cache
+
+    return jax.jit(_decode_all_fed, static_argnames=("cfg",),
                    donate_argnames=("cache",))
 
 
@@ -71,7 +85,15 @@ class ServingEngine:
     The engine owns its cache: every step donates it, so the step updates
     it in place. ``state_tree`` hands out a copy, and ``load_state`` takes
     a copy of the device arrays it is given; neither shares a buffer with
-    the cache a later step consumes."""
+    the cache a later step consumes.
+
+    No step waits for the one before it. Positions, budgets, completions
+    and admissions need no token value, so the host knows them at
+    dispatch; each step's output is queued on its way to the host, and
+    ``sync`` appends the queued tokens to their requests' lists (a
+    ``Completion`` holds the same list) and sets ``last_token``. Every
+    public method that hands out or replaces the state syncs first, and
+    ``process`` and ``step`` return synced."""
 
     def __init__(self, cfg: ModelConfig, params, num_slots: int = 4,
                  max_seq: int = 512, name: str = "engine"):
@@ -87,7 +109,12 @@ class ServingEngine:
         self.request_of_slot: Dict[int, int] = {}
         self.budget = np.zeros(num_slots, np.int64)
         self.generated: Dict[int, List[int]] = {}
-        self.last_token = np.zeros(num_slots, np.int64)
+        self.last_token = np.zeros(num_slots, np.int64)  # as of the sync
+        # on the device: each lane's last sampled token, fed to its next step
+        self._last = jnp.zeros(num_slots, jnp.int32)
+        # steps not yet read: (output on its way to the host,
+        # [(slot, token list its sampled token goes to)])
+        self._pending: List[tuple] = []
         self.waiting: List[Request] = []
         self.completions: List[Completion] = []
         # MS2M bookkeeping
@@ -115,74 +142,83 @@ class ServingEngine:
         token, exactly like a plain prefill+decode."""
         toks = req.prompt or [0]
         self.claimed[slot] = True
-        sampled = 0
+        generated: List[int] = []
         with obs.span("engine.admit", request_id=req.request_id):
             for t, tok in enumerate(toks):
-                next_tok = self._engine_step(forced={slot: (tok, t)})
-                sampled = int(next_tok[slot])
+                into = generated if t == len(toks) - 1 else None
+                self._engine_step(forced={slot: (tok, t, into)})
         self.claimed[slot] = False
         self.positions[slot] = len(toks)
         self.active[slot] = True
         self.request_of_slot[slot] = req.request_id
-        self.generated[req.request_id] = [sampled]
-        self.last_token[slot] = sampled
+        self.generated[req.request_id] = generated
         self.budget[slot] = req.max_new_tokens - 1
         if self.budget[slot] <= 0:
             self._complete(slot)
 
     # ------------------------------------------------------------------- step
     def _engine_step(self, forced: Optional[Dict[int, tuple]] = None):
-        """One batched decode step across all slots.
+        """Dispatch one batched decode step across all slots.
 
-        ``forced`` maps slot -> (token, position): lanes being prefilled
-        consume their prompt token at its position; other active lanes
-        decode their last sampled token; idle lanes take position -1, which
-        writes their own lane's row as empty and routes them to no expert
-        (harmless: a lane's rows are rewritten from position 0 on admit)."""
+        ``forced`` maps slot -> (token, position, into): lanes being
+        prefilled consume their prompt token at its position, and the lane
+        given a list ``into`` samples its first token into it; other active
+        lanes decode their last sampled token, held on the device; idle
+        lanes take position -1, which writes their own lane's row as empty
+        and routes them to no expert (harmless: a lane's rows are rewritten
+        from position 0 on admit)."""
         forced = forced or {}
         # the span ends before admissions, whose prefill runs steps of its
         # own: steps never nest
         with obs.span("engine.step"):
-            tokens = np.zeros((self.num_slots, 1), np.int32)
-            positions = np.full((self.num_slots, 1), -1, np.int32)
-            lanes = 0
+            # forced tokens, positions, lanes fed their last sampled token
+            host = np.zeros((3, self.num_slots), np.int32)
+            host[1] = -1
+            sampled = []
             for s in range(self.num_slots):
                 if s in forced:
-                    tok, pos = forced[s]
-                    tokens[s, 0] = tok
-                    positions[s, 0] = pos
+                    host[0, s], host[1, s], into = forced[s]
+                    if into is not None:
+                        sampled.append((s, into))
                 elif self.active[s]:
-                    tokens[s, 0] = self.last_token[s]
-                    positions[s, 0] = self.positions[s]
-                else:
-                    continue
-                lanes += 1
+                    host[1, s] = self.positions[s]
+                    host[2, s] = 1
+                    rid = self.request_of_slot[s]
+                    sampled.append((s, self.generated[rid]))
             with obs.span("engine.step.dispatch"):
-                next_tok, self.cache = self._step_jit(
-                    self.cache, jnp.asarray(tokens), jnp.asarray(positions))
-            with obs.span("engine.step.wait"):
-                next_tok = np.asarray(next_tok)
-            if next_tok.ndim == 2:
-                next_tok, fetches = next_tok
-                obs.count("moe.expert_loads", int(fetches.sum()))
-            for s in range(self.num_slots):
-                if s in forced:
-                    continue
-                if not self.active[s]:
-                    continue
-                tok = int(next_tok[s])
-                rid = self.request_of_slot[s]
+                out, self._last, self.cache = self._step_jit(
+                    self.cache, jnp.asarray(host), self._last)
+            out.copy_to_host_async()
+            self._pending.append((out, sampled))
+            for s in np.flatnonzero(host[2]):
                 self.positions[s] += 1
-                self.generated[rid].append(tok)
-                self.last_token[s] = tok
                 self.budget[s] -= 1
                 if (self.budget[s] <= 0
                         or self.positions[s] >= self.max_seq - 1):
-                    self._complete(s)
+                    self._complete(int(s))
             obs.count("engine.steps")
-            obs.count("engine.lanes", lanes)
+            obs.count("engine.lanes", int((host[1] >= 0).sum()))
         self._admit_waiting()
-        return next_tok
+
+    def sync(self) -> None:
+        """Read the queued steps' sampled tokens on the host, in step
+        order: each goes to its request's token list, and ``last_token``
+        is each active lane's newest. Returns once the last step
+        dispatched has run."""
+        if not self._pending:
+            return
+        with obs.span("engine.sync"):
+            for out, sampled in self._pending:
+                out = np.asarray(out)
+                if out.ndim == 2:
+                    out, fetches = out
+                    obs.count("moe.expert_loads", int(fetches.sum()))
+                for s, into in sampled:
+                    into.append(int(out[s]))
+            self._pending = []
+            for s, rid in self.request_of_slot.items():
+                self.last_token[s] = self.generated[rid][-1]
+        obs.count("engine.syncs")
 
     def _complete(self, slot: int):
         rid = self.request_of_slot.pop(slot)
@@ -195,6 +231,7 @@ class ServingEngine:
         for _ in range(n):
             if self.active.any():
                 self._engine_step()
+        self.sync()
 
     # ------------------------------------------------------- MS2M worker API
     def process(self, msg) -> None:
@@ -208,6 +245,7 @@ class ServingEngine:
         while req.request_id in self.generated or any(
                 r.request_id == req.request_id for r in self.waiting):
             self._engine_step()
+        self.sync()
         self.last_msg_id = msg.msg_id
         self.n_processed += 1
 
@@ -222,6 +260,7 @@ class ServingEngine:
         so checkpoints are only taken between admissions (the serving
         wrapper guarantees this by draining ``waiting`` before yielding
         control)."""
+        self.sync()
         if self.waiting:
             raise RuntimeError(
                 f"{self.name}: state_tree() with {len(self.waiting)} "
@@ -258,6 +297,7 @@ class ServingEngine:
         """Restore from ``tree``: host leaves are put on the device, device
         leaves copied, so the caller's tree stays valid after later
         (donated) steps."""
+        self.sync()
         with obs.span("engine.load"):
             leaves = jax.tree.leaves(tree["cache"])
             obs.count("restore.h2d_bytes", sum(
@@ -273,6 +313,7 @@ class ServingEngine:
         self.active = np.asarray(slots["active"]).copy()
         self.budget = np.asarray(slots["budget"]).copy()
         self.last_token = np.asarray(slots["last_token"]).copy()
+        self._last = jnp.asarray(self.last_token.astype(np.int32))
         self.last_msg_id = int(tree["scalars"]["last_msg_id"])
         self.n_processed = int(tree["scalars"]["n_processed"])
         self.request_of_slot = {}
@@ -289,6 +330,8 @@ class ServingEngine:
                                        for t in gen[s, : int(gen_len[s])]]
 
     def state_equal(self, other, exact: bool = True) -> bool:
+        self.sync()
+        other.sync()
         if self.last_msg_id != other.last_msg_id:
             return False
         for a, b in zip(jax.tree.leaves(self.cache),
@@ -305,6 +348,7 @@ class ServingEngine:
 
     def slot_table(self) -> List[Dict[str, int]]:
         """Human-readable view of the in-flight slots (handoff telemetry)."""
+        self.sync()
         return [{"slot": s, "request_id": rid,
                  "position": int(self.positions[s]),
                  "generated": len(self.generated[rid]),

@@ -271,6 +271,7 @@ class ServingWorker:
             for _ in range(self.decode_rounds):
                 if eng.active.any():
                     eng._engine_step()
+            eng.sync()
             eng.last_msg_id = msg.msg_id
             eng.n_processed += 1
         self._drain_completions()
@@ -337,6 +338,7 @@ class ServingWorker:
                 raise RuntimeError(f"{eng.name}: flush did not converge")
             eng._engine_step()
             n += 1
+        eng.sync()
         self._drain_completions()
         return n
 

@@ -71,9 +71,10 @@ def host_events(logdir):
 def traced(model, tmp_path_factory):
     cfg, params = model
     logdir = tmp_path_factory.mktemp("trace")
+    c0 = obs.counters()
     with jax.profiler.trace(str(logdir)):
         engines = workload(cfg, params, tmp_path_factory.mktemp("reg"))
-    return engines, host_events(logdir)
+    return engines, host_events(logdir), diff(c0, obs.counters())
 
 
 def test_results_are_the_same_with_the_profiler_on(model, traced, tmp_path):
@@ -99,10 +100,27 @@ def test_engine_steps_never_nest(traced):
     steps = sorted((s, e) for n, s, e, _ in traced[1] if n == "engine.step")
     assert len(steps) > 10
     assert all(e0 <= s1 for (_, e0), (s1, _) in zip(steps, steps[1:]))
-    # dispatch and wait lie inside a step
-    for name in ("engine.step.dispatch", "engine.step.wait"):
-        for _, s, e, _ in (ev for ev in traced[1] if ev[0] == name):
-            assert any(s0 <= s and e <= e0 for s0, e0 in steps)
+    # dispatch lies inside a step, a sync outside every step
+    dispatches = [(s, e) for n, s, e, _ in traced[1]
+                  if n == "engine.step.dispatch"]
+    assert len(dispatches) == len(steps)
+    for s, e in dispatches:
+        assert any(s0 <= s and e <= e0 for s0, e0 in steps)
+    syncs = [(s, e) for n, s, e, _ in traced[1] if n == "engine.sync"]
+    assert syncs
+    for s, e in syncs:
+        assert not any(s < e0 and s0 < e for s0, e0 in steps)
+
+
+def test_engine_syncs_once_per_message(traced):
+    """The workload processes 6 messages; each ends with the one sync that
+    reads its steps' tokens, and no state handed out or loaded finds a
+    step left to read. Steps outnumber syncs."""
+    names = [e[0] for e in traced[1]]
+    d = traced[2]
+    assert d["engine.syncs"] == names.count("engine.sync") == 6
+    assert d["engine.steps"] == names.count("engine.step")
+    assert d["engine.steps"] / d["engine.syncs"] > 1
 
 
 def test_emitted_spans_are_declared(model, tmp_path, monkeypatch):
@@ -176,6 +194,9 @@ def test_engine_counts_steps_and_lanes(model):
         eng.process(m)
     c1 = obs.counters()
     assert c1["engine.steps"] - c0["engine.steps"] == len(calls) > 0
+    # one sync a message, once its last step is dispatched
+    assert c1["engine.syncs"] - c0["engine.syncs"] == len(msgs)
+    assert len(calls) > len(msgs)
     assert c1["moe.expert_loads"] == c0["moe.expert_loads"]
     # a lane is fed once per prompt token and once per token sampled after
     # the first (the last prompt token's pass samples the first)

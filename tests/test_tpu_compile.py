@@ -172,9 +172,10 @@ def test_decode_step_copies_no_layer_of_the_cache(topo, one_chip,
         functools.partial(T.init_lm, jax.random.PRNGKey(0), cfg)))
     cache = jax.tree.map(on_chip, jax.eval_shape(
         functools.partial(T.init_cache, cfg, 3, 2048)))
-    tok = jax.ShapeDtypeStruct((3, 1), jnp.int32, sharding=one_chip)
+    host = jax.ShapeDtypeStruct((3, 3), jnp.int32, sharding=one_chip)
+    last = jax.ShapeDtypeStruct((3,), jnp.int32, sharding=one_chip)
     step = engine._owned_step(engine._decode_all)
-    compiled = step.lower(params, cfg, cache, tok, tok).compile()
+    compiled = step.lower(params, cfg, cache, host, last).compile()
     text = compiled.as_text()
     layer = 3 * 2048 * cfg.num_kv_heads * cfg.resolved_head_dim
     copies = [[int(d) for d in dims.split(",") if d] for dims in re.findall(
@@ -233,9 +234,10 @@ def test_moe_decode_step_copies_no_expert(topo, one_chip, monkeypatch):
         functools.partial(T.init_lm, jax.random.PRNGKey(0), cfg)))
     cache = jax.tree.map(on_chip, jax.eval_shape(
         functools.partial(T.init_cache, cfg, 3, 4096)))
-    tok = jax.ShapeDtypeStruct((3, 1), jnp.int32, sharding=one_chip)
+    host = jax.ShapeDtypeStruct((3, 3), jnp.int32, sharding=one_chip)
+    last = jax.ShapeDtypeStruct((3,), jnp.int32, sharding=one_chip)
     step = engine._owned_step(engine._decode_all)
-    compiled = step.lower(params, cfg, cache, tok, tok).compile()
+    compiled = step.lower(params, cfg, cache, host, last).compile()
     text = compiled.as_text()
     L, E, d, ff = cfg.num_layers, cfg.num_experts, cfg.d_model, cfg.d_ff
     kernel = [ln for ln in text.splitlines()
