@@ -62,14 +62,18 @@ def _owned_step(step):
     int32 ``[3, B]`` of forced tokens, positions and a mask of the lanes
     fed their own last sampled token, which ``last`` ``[B]`` holds on the
     device. Returns ``step``'s output, the lanes' sampled tokens (the next
-    ``last``) and the cache. The whole step is one program, named after
+    ``last``) and the cache. An idle lane (position -1) keeps its
+    ``last``: a slot whose prompt has just been folded waits idle while
+    prompts admitted in its last step are folded, and then feeds the
+    token its prompt sampled. The whole step is one program, named after
     ``_decode_all``, which takes the donated cache and hands back the
     next, so no array outside it ever holds a donated buffer."""
     def _decode_all_fed(params, cfg, cache, host, last):
         tokens = jnp.where(host[2] != 0, last, host[0])
         out, cache = step(params, cfg, cache, tokens[:, None],
                           host[1][:, None])
-        return out, out if out.ndim == 1 else out[0], cache
+        tok = out if out.ndim == 1 else out[0]
+        return out, jnp.where(host[1] >= 0, tok, last), cache
 
     return jax.jit(_decode_all_fed, static_argnames=("cfg",),
                    donate_argnames=("cache",))
@@ -91,7 +95,7 @@ class ServingEngine:
     and admissions need no token value, so the host knows them at
     dispatch; each step's output is queued on its way to the host, and
     ``sync`` appends the queued tokens to their requests' lists (a
-    ``Completion`` holds the same list) and sets ``last_token``. Every
+    ``Completion`` holds the same list). Every
     public method that hands out or replaces the state syncs first, and
     ``process`` and ``step`` return synced."""
 
@@ -109,7 +113,6 @@ class ServingEngine:
         self.request_of_slot: Dict[int, int] = {}
         self.budget = np.zeros(num_slots, np.int64)
         self.generated: Dict[int, List[int]] = {}
-        self.last_token = np.zeros(num_slots, np.int64)  # as of the sync
         # on the device: each lane's last sampled token, fed to its next step
         self._last = jnp.zeros(num_slots, jnp.int32)
         # steps not yet read: (output on its way to the host,
@@ -202,9 +205,8 @@ class ServingEngine:
 
     def sync(self) -> None:
         """Read the queued steps' sampled tokens on the host, in step
-        order: each goes to its request's token list, and ``last_token``
-        is each active lane's newest. Returns once the last step
-        dispatched has run."""
+        order: each goes to its request's token list. Returns once the
+        last step dispatched has run."""
         if not self._pending:
             return
         with obs.span("engine.sync"):
@@ -216,8 +218,6 @@ class ServingEngine:
                 for s, into in sampled:
                     into.append(int(out[s]))
             self._pending = []
-            for s, rid in self.request_of_slot.items():
-                self.last_token[s] = self.generated[rid][-1]
         obs.count("engine.syncs")
 
     def _complete(self, slot: int):
@@ -225,7 +225,6 @@ class ServingEngine:
         self.completions.append(Completion(rid, self.generated.pop(rid)))
         self.active[slot] = False
         self.positions[slot] = 0
-        self.last_token[slot] = 0
 
     def step(self, n: int = 1):
         for _ in range(n):
@@ -269,9 +268,11 @@ class ServingEngine:
         request = np.full(self.num_slots, -1, np.int64)
         gen_len = np.zeros(self.num_slots, np.int64)
         gen = np.zeros((self.num_slots, self.max_seq), np.int32)
+        last_token = np.zeros(self.num_slots, np.int64)
         for s, rid in self.request_of_slot.items():
             toks = self.generated[rid]
             request[s] = rid
+            last_token[s] = toks[-1]
             gen_len[s] = len(toks)
             gen[s, : len(toks)] = toks
         cache = jax.tree.map(jnp.copy, self.cache)
@@ -282,7 +283,7 @@ class ServingEngine:
                 "positions": self.positions.copy(),
                 "active": self.active.copy(),
                 "budget": self.budget.copy(),
-                "last_token": self.last_token.copy(),
+                "last_token": last_token,
                 "request": request,
                 "gen_len": gen_len,
                 "gen": gen,
@@ -312,8 +313,8 @@ class ServingEngine:
         self.positions = np.asarray(slots["positions"]).copy()
         self.active = np.asarray(slots["active"]).copy()
         self.budget = np.asarray(slots["budget"]).copy()
-        self.last_token = np.asarray(slots["last_token"]).copy()
-        self._last = jnp.asarray(self.last_token.astype(np.int32))
+        self._last = jnp.asarray(
+            np.asarray(slots["last_token"]).astype(np.int32))
         self.last_msg_id = int(tree["scalars"]["last_msg_id"])
         self.n_processed = int(tree["scalars"]["n_processed"])
         self.request_of_slot = {}

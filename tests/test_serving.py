@@ -57,6 +57,15 @@ def test_concurrent_requests_complete(setup):
             break
     assert sorted(c.request_id for c in eng.completions) == list(range(5))
     assert all(len(c.tokens) == 4 for c in eng.completions)
+    # a request admitted inside another's last prompt step keeps the
+    # other's first sampled token: same tokens as a host-synced fold
+    fold = PlainFold(cfg, params, 2, 64)
+    for i in range(5):
+        fold.submit(i, [3 + i, 9], 4)
+    while fold.request_of_slot:
+        fold.step()
+    assert ([(c.request_id, c.tokens) for c in eng.completions]
+            == fold.completions)
 
 
 def test_engine_is_ms2m_migratable(setup):
@@ -149,7 +158,8 @@ class PlainFold:
             jnp.asarray(positions))
         tok = np.asarray(tok)
         tok = tok[0] if tok.ndim == 2 else tok
-        for s, rid in list(self.request_of_slot.items()):
+        for s in sorted(self.request_of_slot):  # slot order, as the engine
+            rid = self.request_of_slot[s]
             if s in forced:
                 continue
             self.generated[rid].append(int(tok[s]))
@@ -187,11 +197,14 @@ class PlainFold:
             if self.budget[s] <= 0:
                 self.complete(s)
 
+    def submit(self, rid, prompt, budget):
+        self.waiting.append((rid, prompt, budget))
+        self.admit()
+
     def process(self, msg, decode_rounds):
         p = msg.payload
         rid = p["request_id"]
-        self.waiting.append((rid, p["prompt"], p["max_new_tokens"]))
-        self.admit()
+        self.submit(rid, p["prompt"], p["max_new_tokens"])
         if decode_rounds is None:
             while rid in self.generated or self.waiting:
                 self.step()
@@ -244,7 +257,7 @@ def test_engine_equals_a_plain_fold(config, decode_rounds):
             prompt = msgs[rid].payload["prompt"]
             assert (len(prompt) + len(eng.generated[rid]) - 1
                     == eng.positions[s])
-            assert eng.last_token[s] == eng.generated[rid][-1]
+            assert int(eng._last[s]) == eng.generated[rid][-1]
         if i == 3:
             tree = eng.state_tree()
     assert decode_rounds is None or eng.request_of_slot  # in flight
@@ -265,6 +278,30 @@ def test_engine_equals_a_plain_fold(config, decode_rounds):
     n = len(replica.completions)
     assert ([(c.request_id, c.tokens) for c in replica.completions]
             == fold.completions[len(fold.completions) - n:])
+
+
+@pytest.mark.parametrize("config", ["smollm_360m", "granite_moe_1b_a400m"])
+def test_backlog_equals_a_plain_fold(config):
+    """Requests submitted together queue for the slots; those admitted
+    inside another request's prompt steps leave every lane's last sampled
+    token as it was, so tokens and cache equal the host-synced fold's."""
+    cfg = configs.get_smoke(config)
+    params = T.init_lm(jax.random.PRNGKey(0), cfg)
+    eng = ServingEngine(cfg, params, num_slots=3, max_seq=32)
+    fold = PlainFold(cfg, params, 3, 32)
+    for m in _fold_messages():
+        p = m.payload
+        eng.submit(Request(p["request_id"], p["prompt"], p["max_new_tokens"]))
+        fold.submit(p["request_id"], p["prompt"], p["max_new_tokens"])
+    while fold.request_of_slot:
+        fold.step()
+    eng.step(64)
+    assert eng._pending == [] and not eng.active.any()
+    assert len(fold.completions) == len(_fold_messages())
+    assert ([(c.request_id, c.tokens) for c in eng.completions]
+            == fold.completions)
+    for a, b in zip(jax.tree.leaves(eng.cache), jax.tree.leaves(fold.cache)):
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
 
 
 def test_engine_runs_decode_all_once_a_step(setup, monkeypatch):
