@@ -95,9 +95,9 @@ class ServingEngine:
     and admissions need no token value, so the host knows them at
     dispatch; each step's output is queued on its way to the host, and
     ``sync`` appends the queued tokens to their requests' lists (a
-    ``Completion`` holds the same list). Every
-    public method that hands out or replaces the state syncs first, and
-    ``process`` and ``step`` return synced."""
+    ``Completion`` holds the same list). Every public method that hands
+    out or replaces the state syncs first, and ``process`` and ``step``
+    return synced."""
 
     def __init__(self, cfg: ModelConfig, params, num_slots: int = 4,
                  max_seq: int = 512, name: str = "engine"):
